@@ -321,7 +321,7 @@ def packed_block_from_csr(
     _check_indices(window_idx, n_samples)
     stride = (block_variants + 7) // 8
     lib = load()
-    native = lib is not None and hasattr(lib, "csr_to_packed_blocks")
+    native = lib is not None
     mode = "native" if native else "python"
     t0 = time.perf_counter()
     with obs.span("ingest.build", mode=mode, variants=int(lens.size)):

@@ -124,10 +124,7 @@ def _compile_cache_status() -> Optional[dict]:
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    try:
-        directory = jax.config.jax_compilation_cache_dir
-    except Exception:  # pragma: no cover - config API drift
-        return None
+    directory = jax.config.jax_compilation_cache_dir
     if not directory:
         return None
     try:

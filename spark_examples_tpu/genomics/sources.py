@@ -1262,7 +1262,7 @@ class _CsrCohort:
         if os.path.exists(path + ".gz") or not os.path.exists(path):
             return None
         lib = load()
-        if lib is None or not hasattr(lib, "parse_cohort_jsonl"):
+        if lib is None:
             return None
         encoded = [cid.encode() for cid in callset_ids]
         blob = b"".join(encoded)

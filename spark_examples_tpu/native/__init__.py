@@ -1,5 +1,7 @@
 """ctypes loader for the native ingest core, with transparent fallback.
 
+The library is built from the committed ``genomics_native.cpp`` into
+``_genomics_native.<sha12 of the source>.so`` on first use (git-ignored);
 ``load()`` returns the compiled library handle or ``None``; callers keep a
 pure-Python path so the framework runs on hosts without a toolchain (set
 ``SPARK_EXAMPLES_TPU_NO_NATIVE=1`` to force the fallback — used by tests to
@@ -69,7 +71,19 @@ class CohortCsr(ctypes.Structure):
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "genomics_native.cpp")
-_SO = os.path.join(_HERE, "_genomics_native.so")
+
+
+def _source_digest() -> str:
+    import hashlib
+
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()[:12]
+
+
+# The library is keyed by the CONTENT of the committed source: a build
+# from another revision (or a file copied in with a fresh mtime) can never
+# be taken for this one, and a changed source simply builds a new file.
+_SO = os.path.join(_HERE, f"_genomics_native.{_source_digest()}.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -135,15 +149,7 @@ def load() -> Optional[ctypes.CDLL]:
             _tried = True
             return _lib
         _tried = True
-        try:
-            stale = not os.path.exists(_SO) or (
-                os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-            )
-        except OSError:
-            # Source missing (e.g. a deployed tree shipping only the .so):
-            # treat the existing library as current.
-            stale = not os.path.exists(_SO)
-        if stale and not _build():
+        if not os.path.exists(_SO) and not _build():
             return None
         try:
             lib = ctypes.CDLL(_SO)
@@ -163,18 +169,15 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_int64,
         ctypes.c_void_p,
     ]
-    if hasattr(lib, "csr_to_packed_blocks"):
-        # Absent from pre-PR-6 deployed .so files; callers probe
-        # with hasattr and fall back to the numpy pack.
-        lib.csr_to_packed_blocks.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_void_p,
-            ctypes.c_int64,
-            ctypes.c_int64,
-            ctypes.c_int64,
-            ctypes.c_void_p,
-        ]
-        lib.csr_to_packed_blocks.restype = ctypes.c_int64
+    lib.csr_to_packed_blocks.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_void_p,
+        ctypes.c_int64,
+        ctypes.c_int64,
+        ctypes.c_int64,
+        ctypes.c_void_p,
+    ]
+    lib.csr_to_packed_blocks.restype = ctypes.c_int64
     lib.murmur3_x64_128.argtypes = [
         ctypes.c_void_p,
         ctypes.c_int64,
@@ -188,24 +191,23 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_uint64,
         ctypes.c_void_p,
     ]
-    # Bind the cohort parser only when the library's struct layout
-    # matches this module's ctypes mirror: a deployed tree may ship
-    # an older .so, and reading an old struct through a newer layout
-    # would silently misalign every pointer after the changed field.
+    # The ctypes CohortCsr mirror above must match the struct the
+    # library was built with.
     _ABI = 2
-    abi_ok = False
-    if hasattr(lib, "cohort_csr_abi_version"):
-        lib.cohort_csr_abi_version.restype = ctypes.c_int64
-        abi_ok = lib.cohort_csr_abi_version() == _ABI
-    if abi_ok and hasattr(lib, "parse_cohort_jsonl"):
-        lib.parse_cohort_jsonl.argtypes = [
-            ctypes.c_char_p,
-            ctypes.c_void_p,
-            ctypes.c_void_p,
-            ctypes.c_int64,
-        ]
-        lib.parse_cohort_jsonl.restype = ctypes.POINTER(CohortCsr)
-        lib.cohort_csr_free.argtypes = [ctypes.POINTER(CohortCsr)]
+    lib.cohort_csr_abi_version.restype = ctypes.c_int64
+    if lib.cohort_csr_abi_version() != _ABI:
+        raise OSError(
+            f"native CohortCsr ABI {lib.cohort_csr_abi_version()} != "
+            f"the loader's mirror ({_ABI})"
+        )
+    lib.parse_cohort_jsonl.argtypes = [
+        ctypes.c_char_p,
+        ctypes.c_void_p,
+        ctypes.c_void_p,
+        ctypes.c_int64,
+    ]
+    lib.parse_cohort_jsonl.restype = ctypes.POINTER(CohortCsr)
+    lib.cohort_csr_free.argtypes = [ctypes.POINTER(CohortCsr)]
     return lib
 
 

@@ -4,7 +4,7 @@ The reference repo's only observability was six driver-merged
 accumulators plus the Spark UI (SURVEY.md §5); rounds 1-5 reproduced
 exactly that (``utils/stats.py``) plus a wall-clock ``StageTimer``
 (``utils/tracing.py``) — not enough to diagnose the round-5 remote-tier
-stalls without log archaeology (NOTES.md). This package is the
+stalls without log archaeology. This package is the
 first-class telemetry layer every subsequent perf PR measures itself
 with. Three pillars:
 

@@ -76,23 +76,22 @@ def _chunk_variants() -> int:
 def kernel_block_rows(t_r: int, t_c: int, k: int = 0) -> Optional[int]:
     """Largest VMEM-fitting row-block size for a (t_r, t_c) f32 tile.
 
-    The kernel holds per grid step: the g input block + output block
-    (2·BR·TC·4 B), the chunk's one-hot count transients
-    (C·(BR+TC)·4 B), and the two (C, K) int32 index blocks — NOT small
-    at biobank carrier buckets (K=16384 alone is 33.5 MB), so ``k``
-    must be charged when known (the kernel dispatch knows it at trace
-    time; the resolve-time heuristic passes 0 and the dispatch
-    re-checks with the real bucket, falling back to scan). Returns a
-    sublane-aligned divisor of ``t_r``, or ``None`` when even the
-    minimum 8-row block cannot fit — the dispatcher then uses the scan
-    path rather than compile a kernel that cannot stage.
+    The kernel holds per grid step: the g input block + output block,
+    each double-buffered by the Pallas pipeline (4·BR·TC·4 B); the
+    chunk's one-hot count scratch (C·(BR+TC)·4 B); and the two (K, C)
+    int32 index blocks, double-buffered (4·K·C·4 B) — NOT small at
+    biobank carrier buckets, so ``k`` must be charged when known (the
+    host dispatch knows it per window: :func:`window_scatter_path`;
+    the resolve-time heuristic passes 0). Returns a sublane-aligned
+    divisor of ``t_r``, or ``None`` when even the minimum 8-row block
+    cannot fit.
     """
     c = _chunk_variants()
-    # The (C, TC) col-count transient + the two (C, K) index blocks.
-    budget = _vmem_budget() - c * t_c * 4 - 2 * c * k * 4
+    # The (TC, C) col-count scratch + the double-buffered index blocks.
+    budget = _vmem_budget() - c * t_c * 4 - 4 * c * k * 4
     if budget <= 0:
         return None
-    cap = budget // (2 * t_c * 4 + c * 4)  # g in+out blocks + row counts
+    cap = budget // (4 * t_c * 4 + c * 4)  # g in+out blocks + row counts
     cap = min(t_r, (cap // _SUBLANE) * _SUBLANE)
     br = cap
     while br >= _SUBLANE:
@@ -111,13 +110,6 @@ def _kernel_eligible(tile_shape: Tuple[int, int], dtype) -> bool:
     if t_r % _SUBLANE or t_c % _LANE:
         return False
     return kernel_block_rows(t_r, t_c) is not None
-
-
-def _mosaic_backend() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover — backend probe failure
-        return False
 
 
 def resolve_scatter_path(tile_shape: Tuple[int, int], dtype=np.float32):
@@ -139,15 +131,47 @@ def resolve_scatter_path(tile_shape: Tuple[int, int], dtype=np.float32):
         return "scan"
     if mode == "interpret":
         return "interpret"
-    if _mosaic_backend():
+    if jax.default_backend() == "tpu":
         return "pallas"
     return "scan"
 
 
-def _scatter_kernel_body(br: int, t_c: int, k: int, c: int):
-    """Kernel closure for fixed block geometry (all shapes static)."""
+def window_scatter_path(
+    path: str, tile_shape: Tuple[int, int], k: int
+) -> str:
+    """The path ONE window's scatter takes, counted — host side, per window.
 
-    def kernel(row_ref, col_ref, g_ref, out_ref):
+    The stream's ``path`` (:func:`resolve_scatter_path`) cannot see the
+    carrier bucket ``k``, which varies per window. A kernel path whose
+    (K, C) index blocks push a grid step over the VMEM budget takes the
+    chunked scan instead (bit-identical), and that fall is counted as
+    ``path="fallback-scan"`` in ``sparse_scatter_path_windows_total`` —
+    never decided silently inside a trace.
+    """
+    taken = path
+    if path != "scan" and kernel_block_rows(*tile_shape, k) is None:
+        taken = "scan"
+    from spark_examples_tpu import obs
+
+    obs.get_registry().counter(
+        "sparse_scatter_path_windows_total",
+        "Scatter-route windows by the scatter implementation they took "
+        "(fallback-scan = a kernel stream's window over the VMEM budget)",
+    ).labels(path="fallback-scan" if taken != path else taken).inc()
+    return taken
+
+
+def _scatter_kernel_body(br: int, t_c: int, k: int, c: int):
+    """Kernel closure for fixed block geometry (all shapes static).
+
+    Index blocks arrive transposed, ``(K, C)``: carrier ``a`` of the
+    chunk is one sublane row (a dynamic sublane slice Mosaic lowers),
+    broadcast against a sample iota laid out along sublanes, so both
+    one-hot count panels keep the chunk's C variants on the lane axis
+    and the outer-product sum is an A·Bᵀ contraction over lanes.
+    """
+
+    def kernel(row_ref, col_ref, g_ref, out_ref, r_cnt_ref, c_cnt_ref):
         from jax.experimental import pallas as pl
 
         j = pl.program_id(1)  # carrier-chunk position (innermost)
@@ -160,30 +184,21 @@ def _scatter_kernel_body(br: int, t_c: int, k: int, c: int):
             out_ref[:] = g_ref[:]
 
         base = pl.program_id(0) * br
-        ri = row_ref[:]  # (C, K) int32, OOB = sentinel >= t_r
-        cj = col_ref[:]
-        row_iota = (
-            jax.lax.broadcasted_iota(jnp.int32, (c, br), 1) + base
-        )
-        col_iota = jax.lax.broadcasted_iota(jnp.int32, (c, t_c), 1)
+        r_cnt_ref[:] = jnp.zeros((br, c), jnp.float32)
+        c_cnt_ref[:] = jnp.zeros((t_c, c), jnp.float32)
 
         def body(a, carry):
-            r_cnt, c_cnt = carry
-            r = jax.lax.dynamic_slice(ri, (0, a), (c, 1))
-            cc = jax.lax.dynamic_slice(cj, (0, a), (c, 1))
-            r_cnt = r_cnt + (row_iota == r).astype(jnp.float32)
-            c_cnt = c_cnt + (col_iota == cc).astype(jnp.float32)
-            return r_cnt, c_cnt
+            r = row_ref[pl.ds(a, 1), :]  # (1, C) int32, OOB >= t_r
+            cc = col_ref[pl.ds(a, 1), :]
+            row_iota = (
+                jax.lax.broadcasted_iota(jnp.int32, (br, c), 0) + base
+            )
+            col_iota = jax.lax.broadcasted_iota(jnp.int32, (t_c, c), 0)
+            r_cnt_ref[:] += (row_iota == r).astype(jnp.float32)
+            c_cnt_ref[:] += (col_iota == cc).astype(jnp.float32)
+            return carry
 
-        r_cnt, c_cnt = jax.lax.fori_loop(
-            0,
-            k,
-            body,
-            (
-                jnp.zeros((c, br), jnp.float32),
-                jnp.zeros((c, t_c), jnp.float32),
-            ),
-        )
+        jax.lax.fori_loop(0, k, body, 0)
         # Σ_v r_v · c_vᵀ over the chunk: one MXU contraction — counts
         # are exact small integers in f32, so the add is exact.
         # precision=HIGHEST: the default matmul precision routes f32
@@ -192,9 +207,9 @@ def _scatter_kernel_body(br: int, t_c: int, k: int, c: int):
         # bit-identity contract exactly on the backend that
         # auto-selects this kernel.
         out_ref[:] += jax.lax.dot_general(
-            r_cnt,
-            c_cnt,
-            (((0,), (0,)), ((), ())),
+            r_cnt_ref[:],
+            c_cnt_ref[:],
+            (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
         )
@@ -208,32 +223,42 @@ def scatter_pairs_kernel(g, row_idx, col_idx, interpret: bool = False):
     Drop-in for :func:`spark_examples_tpu.ops.sparse.scatter_pairs_chunked`
     (same operands, same OOB-drop and duplicate semantics, bit-identical
     result); callers must have resolved eligibility via
-    :func:`resolve_scatter_path` first. Traceable under jit/shard_map.
-    The resolve-time budget check cannot see the carrier bucket K (it
-    varies per window); this dispatch re-checks with the REAL K and
-    falls back to the scan body — bit-identical — when the index
-    blocks push the grid step over the VMEM budget.
+    :func:`resolve_scatter_path` and, per window, the carrier bucket via
+    :func:`window_scatter_path` first — a geometry that cannot stage is
+    an error here, not a quiet change of implementation.
+    Traceable under jit/shard_map.
     """
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     t_r, t_c = g.shape
     v_pad, k = row_idx.shape
     c = _chunk_variants()
     br = kernel_block_rows(t_r, t_c, k)
     if br is None:
-        from spark_examples_tpu.ops.sparse import scatter_pairs_chunked
-
-        return scatter_pairs_chunked(g, row_idx, col_idx)
+        raise ValueError(
+            f"scatter kernel cannot stage a ({t_r}, {t_c}) tile with "
+            f"carrier bucket {k} in {_vmem_budget()} B of VMEM; the "
+            "dispatch must route this window to the scan path"
+        )
     grid = (t_r // br, v_pad // c)
     return pl.pallas_call(
         _scatter_kernel_body(br, t_c, k, c),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((c, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((c, k), lambda i, j: (j, 0)),
+            pl.BlockSpec((k, c), lambda i, j: (0, j)),
+            pl.BlockSpec((k, c), lambda i, j: (0, j)),
             pl.BlockSpec((br, t_c), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((br, t_c), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t_r, t_c), g.dtype),
+        # Inside shard_map the result varies over the same mesh axes as
+        # the tile it accumulates into.
+        out_shape=jax.ShapeDtypeStruct(
+            (t_r, t_c), g.dtype, vma=jax.typeof(g).vma
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((br, c), jnp.float32),
+            pltpu.VMEM((t_c, c), jnp.float32),
+        ],
         interpret=interpret,
-    )(row_idx, col_idx, g)
+    )(row_idx.T, col_idx.T, g)

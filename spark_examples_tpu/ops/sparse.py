@@ -268,7 +268,10 @@ def sparse_gramian_accumulate(g, window_idx, lens, scatter_path=None):
     pre-resolves the scan-vs-Pallas-kernel choice for streams that
     dispatch many windows (resolved per call here when ``None``).
     """
-    from spark_examples_tpu.ops.scatter_kernel import resolve_scatter_path
+    from spark_examples_tpu.ops.scatter_kernel import (
+        resolve_scatter_path,
+        window_scatter_path,
+    )
 
     if scatter_path is None:
         scatter_path = resolve_scatter_path(g.shape, g.dtype)
@@ -278,7 +281,8 @@ def sparse_gramian_accumulate(g, window_idx, lens, scatter_path=None):
         sentinel=g.shape[0],
         n_rows=_pad_rows_for_scan(np.asarray(lens).size),
     )
-    return _scatter_accumulate_jit(g, idx, path=scatter_path)
+    path = window_scatter_path(scatter_path, g.shape, idx.shape[1])
+    return _scatter_accumulate_jit(g, idx, path=path)
 
 
 def _note_window(route: str, nnz: int, count: int = 1) -> None:

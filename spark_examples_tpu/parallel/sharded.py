@@ -69,25 +69,6 @@ def _mesh_axes(mesh: Mesh):
     return DATA_AXIS, (MODEL_AXIS if has_model else None)
 
 
-def _shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across the jax versions this tree runs on.
-
-    Newer jax exposes it at top level (with ``check_vma``); 0.4.x keeps
-    it in ``jax.experimental.shard_map`` where the same knob is spelled
-    ``check_rep``. One seam so every per-device kernel here stays
-    runnable on both.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def gramian_variant_parallel(x, mesh: Mesh, compute_dtype=None):
     """``G = psum_over_devices(X_loc @ X_loc.T)`` with X variant-sharded.
 
@@ -99,7 +80,7 @@ def gramian_variant_parallel(x, mesh: Mesh, compute_dtype=None):
     )
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(None, DATA_AXIS),
         out_specs=P(None, None),
@@ -382,7 +363,7 @@ def gramian_variant_parallel_ring(x, mesh: Mesh, compute_dtype=None):
     perm = [(j, (j + 1) % n_dev) for j in range(n_dev)]
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(None, DATA_AXIS),
         out_specs=P(None, None),
@@ -725,7 +706,7 @@ def _sparse_tile_kernels(
         return _scatter_impl(g_tile, li, lj)
 
     scatter = jax.jit(
-        _shard_map(
+        jax.shard_map(
             _tile_scatter,
             mesh=mesh,
             in_specs=(P(d_axis, m_axis), P(None, None)),
@@ -805,7 +786,7 @@ def _sparse_tile_kernels(
         )
 
     accum_dense_pod = jax.jit(
-        _shard_map(
+        jax.shard_map(
             _tile_dense_pod,
             mesh=mesh,
             in_specs=(P(d_axis, m_axis), P(None, all_axes)),
@@ -852,7 +833,7 @@ def _sparse_tile_kernels(
             )
 
         mirror_fill = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 _mirror_tiles,
                 mesh=mesh,
                 in_specs=P(d_axis, m_axis),
@@ -1448,7 +1429,10 @@ def sparse_sharded_gramian_blockwise(
         jnp.int8, accum_dtype, compute_dtype
     )
     width = block_variants or DEFAULT_BLOCK_VARIANTS
-    from spark_examples_tpu.ops.scatter_kernel import resolve_scatter_path
+    from spark_examples_tpu.ops.scatter_kernel import (
+        resolve_scatter_path,
+        window_scatter_path,
+    )
 
     # One scan-vs-Pallas resolution per stream, OUTSIDE any trace; part
     # of the executable cache key so the env switch is honored per run.
@@ -1462,20 +1446,29 @@ def sparse_sharded_gramian_blockwise(
     mirror = (
         spans and grid_rows == grid_cols and grid_rows > 1
     )
-    scatter, _accum_dense, _accum_dense_pod, _mirror_fill = (
-        _sparse_tile_kernels(
-            mesh,
-            d_axis,
-            m_axis,
-            n_padded,
-            tile_rows,
-            tile_cols,
-            np.dtype(accum_dtype).name,
-            np.dtype(compute_dtype).name,
-            scatter_path,
-            mirror,
-        )
+    kernel_key = (
+        mesh,
+        d_axis,
+        m_axis,
+        n_padded,
+        tile_rows,
+        tile_cols,
+        np.dtype(accum_dtype).name,
+        np.dtype(compute_dtype).name,
     )
+    _, _accum_dense, _accum_dense_pod, _mirror_fill = _sparse_tile_kernels(
+        *kernel_key, scatter_path, mirror
+    )
+
+    def scatter(g, idx):
+        # The carrier bucket K is known only per window: the stream's
+        # kernel path may have to take the scan kernel set for this one
+        # (window_scatter_path counts that fall).
+        path = window_scatter_path(
+            scatter_path, (tile_rows, tile_cols), int(idx.shape[1])
+        )
+        return _sparse_tile_kernels(*kernel_key, path, mirror)[0](g, idx)
+
     idx_sharding = NamedSharding(mesh, P(None, None))
     g = jax.device_put(
         jnp.zeros((n_padded, n_padded), dtype=accum_dtype), g_sharding
@@ -1811,7 +1804,7 @@ def _sketch_pod_dense_kernel(mesh: Mesh):
         return y_loc + jax.lax.psum(contrib, all_axes)
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             _step,
             mesh=mesh,
             in_specs=(P(None, None), P(None, all_axes), P(None, None)),
@@ -1853,7 +1846,7 @@ def sketch_tsqr(y, mesh: Mesh):
         return q1 @ q2_i, r
 
     fn = jax.jit(
-        _shard_map(
+        jax.shard_map(
             _local,
             mesh=mesh,
             in_specs=flat,

@@ -2,6 +2,8 @@
 
 import os
 
+import pytest
+
 import jax
 
 
@@ -333,18 +335,79 @@ def test_graft_entry_contract():
         ge.dryrun_multichip(n)
 
 
-def test_compile_cache_dir_is_host_keyed(tmp_path):
-    """The persistent XLA cache dir must embed the host CPU feature set so
-    a cache populated on a different host can never feed this one illegal
-    instructions (round-2 bench tail SIGILL-risk warning)."""
+def test_compile_cache_env_dir_is_used_verbatim(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR names the cache, and the program sets no
+    other directory (JAX itself reads the variable)."""
     from spark_examples_tpu.utils.compile_cache import (
         compilation_cache_dir,
-        host_feature_key,
+        enable_persistent_cache,
     )
 
-    key = host_feature_key()
-    assert len(key) == 12
-    assert key == host_feature_key()  # stable within a host
-    path = compilation_cache_dir(str(tmp_path))
-    assert os.path.isdir(path)
-    assert os.path.basename(path) == f"host-{key}"
+    want = str(tmp_path / "x")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    monkeypatch.delenv("SPARK_EXAMPLES_TPU_COMPILE_CACHE", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    assert compilation_cache_dir() == want
+    assert enable_persistent_cache() == want
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not os.path.exists(want)  # JAX creates it on first write
+
+
+def test_compile_cache_default_is_checkout_jax_cache(monkeypatch):
+    """Unset: the fixed ``<checkout>/.jax_cache`` (no per-host, -process
+    or -time name); ``SPARK_EXAMPLES_TPU_COMPILE_CACHE=0`` turns it off."""
+    from spark_examples_tpu.utils.compile_cache import compilation_cache_dir
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("SPARK_EXAMPLES_TPU_COMPILE_CACHE", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compilation_cache_dir() == os.path.join(root, ".jax_cache")
+    monkeypatch.setenv("SPARK_EXAMPLES_TPU_COMPILE_CACHE", "0")
+    assert compilation_cache_dir() is None
+
+
+@pytest.fixture
+def restore_cache_switch():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    was_dir = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    jax.config.update("jax_compilation_cache_dir", was_dir)
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_off_switch_beats_env_dir(
+    monkeypatch, tmp_path, restore_cache_switch
+):
+    """``SPARK_EXAMPLES_TPU_COMPILE_CACHE=0`` turns JAX's own switch off,
+    so no cache is used even where ``JAX_COMPILATION_CACHE_DIR`` (which
+    JAX read at import) names one."""
+    from spark_examples_tpu.utils.compile_cache import enable_persistent_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "x"))
+    monkeypatch.setenv("SPARK_EXAMPLES_TPU_COMPILE_CACHE", "0")
+    assert enable_persistent_cache() is None
+    assert jax.config.jax_enable_compilation_cache is False
+    monkeypatch.delenv("SPARK_EXAMPLES_TPU_COMPILE_CACHE")
+    assert enable_persistent_cache() == str(tmp_path / "x")
+    assert jax.config.jax_enable_compilation_cache is True
+
+
+def test_compile_cache_unusable_default_runs_without_cache(
+    monkeypatch, tmp_path, restore_cache_switch, caplog
+):
+    """A default directory that cannot be made (a read-only or odd
+    install) is logged and the run goes on with no persistent cache."""
+    from spark_examples_tpu.utils import compile_cache
+
+    blocker = tmp_path / "not-a-dir"
+    blocker.write_text("")
+    monkeypatch.setattr(compile_cache, "_CHECKOUT_ROOT", str(blocker))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("SPARK_EXAMPLES_TPU_COMPILE_CACHE", raising=False)
+    with caplog.at_level("WARNING"):
+        assert compile_cache.enable_persistent_cache() is None
+    assert "running without a persistent cache" in caplog.text
+    assert jax.config.jax_enable_compilation_cache is False

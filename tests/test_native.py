@@ -311,3 +311,26 @@ class TestNativeSoOverride:
         )
         assert proc.returncode == 0, proc.stderr + proc.stdout
         assert "raised twice" in proc.stdout
+
+
+class TestContentKeyedBuild:
+    def test_library_file_is_keyed_by_source_content(
+        self, monkeypatch, tmp_path
+    ):
+        """The library loaded is the one built from THIS source: its file
+        name carries the source's content hash (never an mtime check), so
+        an edited source can never load a library built from another."""
+        import hashlib
+        import os
+
+        from spark_examples_tpu import native
+
+        with open(native._SRC, "rb") as f:
+            src = f.read()
+        digest = hashlib.sha256(src).hexdigest()[:12]
+        assert os.path.basename(native._SO) == f"_genomics_native.{digest}.so"
+        assert load() is not None and os.path.exists(native._SO)
+        edited = tmp_path / "genomics_native.cpp"
+        edited.write_bytes(src + b"\n// edited\n")
+        monkeypatch.setattr(native, "_SRC", str(edited))
+        assert native._source_digest() != digest

@@ -125,8 +125,10 @@ class TestEndToEnd:
 
     def _degenerate_merge_driver(self, mode):
         """A same-seed two-dataset merge: duplicated sample rows make
-        the centered Gramian exactly rank-deficient — the cohort shape
-        that collapses the fused CholeskyQR panel to NaN."""
+        the centered Gramian exactly rank-deficient (rank ≤ 7 for 16
+        samples, under the 10-wide fused panel) — the cohort shape the
+        fused finish's explicit panel-rank check must catch, whatever
+        the runtime's arithmetic does with CholeskyQR afterwards."""
         from spark_examples_tpu.genomics.sources import FixtureSource
 
         a = synthetic_cohort(8, 60, variant_set_id="setA", seed=1)
@@ -141,11 +143,10 @@ class TestEndToEnd:
         return VariantsPcaDriver(conf, merged)
 
     def test_degenerate_cohort_auto_falls_back_to_dense_eigh(self):
-        """AUTO selection must not die on a numerically degenerate
-        centered Gramian: the fused finish's panel collapse warns and
-        falls back to dense eigh (exact on rank-deficient spectra),
-        finishing with finite coordinates — the fix for the historical
-        multi-dataset/elastic tier-1 failure family."""
+        """AUTO selection must not die on a rank-deficient centered
+        Gramian: the fused finish's rank check warns and falls back to
+        dense eigh (exact on rank-deficient spectra), finishing with
+        finite coordinates."""
         driver = self._degenerate_merge_driver("auto")
         with pytest.warns(UserWarning, match="dense-eigh finish"):
             result = driver.run()
@@ -155,9 +156,11 @@ class TestEndToEnd:
 
     def test_degenerate_cohort_forced_fused_still_raises(self):
         """--pca-mode fused asked for exactly that program: the
-        degenerate-panel collapse stays a hard error there."""
+        rank-deficient panel stays a hard error there."""
+        from spark_examples_tpu.ops.fused import RankDeficientGramianError
+
         driver = self._degenerate_merge_driver("fused")
-        with pytest.raises(FloatingPointError, match="non-finite"):
+        with pytest.raises(RankDeficientGramianError, match="rank-deficient"):
             driver.run()
 
 
@@ -279,7 +282,7 @@ class TestFusedPcaMode:
 def test_stream_similarity_host_memory_fence():
     """The stream alternate now runs through the sparse device engine:
     the bound is the streaming-sparse per-host footprint (the f32 G
-    tiles), NOT the historical 16·N² host peak (NOTES.md verdict #7) —
+    tiles), NOT the historical 16·N² host peak (round-4 verdict) —
     past it the refusal is still loud, never a silent OOM. The full
     bound matrix lives in tests/test_sparse_gramian.py."""
     conf = PcaConfig(variant_set_ids=[DEFAULT_VARIANT_SET_ID], block_variants=32)

@@ -21,6 +21,7 @@ from spark_examples_tpu.ops.scatter_kernel import (
     kernel_block_rows,
     resolve_scatter_path,
     scatter_pairs_kernel,
+    window_scatter_path,
 )
 from spark_examples_tpu.ops.sparse import (
     SCATTER_CHUNK_VARIANTS,
@@ -107,9 +108,9 @@ class TestKernelBitIdentity:
         want = np.asarray(scatter_pairs_chunked(g, row, col))
         monkeypatch.setenv(
             "SPARK_EXAMPLES_TPU_SCATTER_KERNEL_VMEM",
-            str(SCATTER_CHUNK_VARIANTS * 128 * 4 + 2 * 8 * 128 * 4
+            str(SCATTER_CHUNK_VARIANTS * 128 * 4 + 4 * 8 * 128 * 4
                 + SCATTER_CHUNK_VARIANTS * 8 * 4
-                + 2 * SCATTER_CHUNK_VARIANTS * 16 * 4),
+                + 4 * SCATTER_CHUNK_VARIANTS * 16 * 4),
         )
         assert kernel_block_rows(64, 128, 16) == 8
         got = np.asarray(
@@ -117,13 +118,16 @@ class TestKernelBitIdentity:
         )
         np.testing.assert_array_equal(want, got)
 
-    def test_oversized_carrier_bucket_falls_back_in_dispatch(
+    def test_oversized_carrier_bucket_falls_back_per_window(
         self, monkeypatch
     ):
         """The resolve-time budget check cannot see K (it varies per
-        window): a carrier bucket whose (C, K) index blocks blow the
-        budget must fall back to the scan body INSIDE the dispatch,
-        bit-identically — never a Mosaic staging error mid-stream."""
+        window): a carrier bucket whose (K, C) index blocks blow the
+        budget takes the scan body, decided and COUNTED host-side per
+        window — the kernel itself refuses rather than quietly
+        switching implementation inside a trace."""
+        from spark_examples_tpu import obs
+
         rng = np.random.default_rng(9)
         k = 64
         g, row, col = _random_case(
@@ -132,9 +136,10 @@ class TestKernelBitIdentity:
         # Budget passes the resolve-time check (k unknown → 0) but not
         # the dispatch's real-K check.
         budget = (
-            SCATTER_CHUNK_VARIANTS * 128 * 4 + 2 * 8 * 128 * 4
-            + SCATTER_CHUNK_VARIANTS * 8 * 4 + 1024
-        )
+            SCATTER_CHUNK_VARIANTS * 128 * 4 + 4 * 8 * 128 * 4
+            + SCATTER_CHUNK_VARIANTS * 8 * 4
+            + 4 * SCATTER_CHUNK_VARIANTS * 8 * 4 + 1024
+        )  # fits the smallest bucket (8), not k
         monkeypatch.setenv(
             "SPARK_EXAMPLES_TPU_SCATTER_KERNEL_VMEM", str(budget)
         )
@@ -143,11 +148,19 @@ class TestKernelBitIdentity:
         )
         assert resolve_scatter_path((64, 128)) == "interpret"
         assert kernel_block_rows(64, 128, k) is None
-        want = np.asarray(scatter_pairs_chunked(g, row, col))
-        got = np.asarray(
+        with pytest.raises(ValueError, match="route this window"):
             scatter_pairs_kernel(g, row, col, interpret=True)
+        counter = obs.get_registry().counter(
+            "sparse_scatter_path_windows_total"
         )
-        np.testing.assert_array_equal(want, got)
+        before = {
+            p: counter.labels(path=p).value
+            for p in ("fallback-scan", "interpret")
+        }
+        assert window_scatter_path("interpret", (64, 128), k) == "scan"
+        assert window_scatter_path("interpret", (64, 128), 8) == "interpret"
+        for p in before:
+            assert counter.labels(path=p).value == before[p] + 1
 
 
 class TestDispatcher:

@@ -5,7 +5,7 @@ bit-identical to the dense integer-exact reference across mesh shapes
 (1×1, 2×1, 2×2 host-device meshes), shuffled window orders, and density
 edge cases; the per-window dense/sparse switch; the per-host
 sample-range ingest contract; the streaming-sparse footprint bound that
-replaced NOTES.md verdict #7's 16·N² host refusal; the centralized
+replaced the round-4 verdict's 16·N² host refusal; the centralized
 k+1-values panel convention; and the ``--pca-mode sparse`` CLI route
 with schema-valid telemetry. The N=65536 acceptance run is the ``slow``
 test at the bottom.
@@ -427,7 +427,7 @@ class TestDriverSparseMode:
 
 
 class TestStreamFootprintBound:
-    """Satellite: the 16·N² host refusal (NOTES.md verdict #7) is gone;
+    """Satellite: the 16·N² host refusal (round-4 verdict) is gone;
     the bound is the streaming-sparse per-host G footprint."""
 
     def _driver(self, mesh_spec=None):

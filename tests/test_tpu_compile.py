@@ -1,0 +1,132 @@
+"""The main path's TPU programs compile for a described v5e chip.
+
+No chip is attached here: the TPU compiler compiles for a topology that
+is only described (``jax.experimental.topologies``), which catches what
+interpret mode and the CPU backend cannot — a Pallas kernel Mosaic
+refuses, a block over the VMEM budget, a program that does not fit.
+Nothing runs, so these tests say nothing about results or times.
+
+The topology is described only inside the module fixture below (never at
+import time, in a ``skipif`` or in ``parametrize``): only one process may
+load the TPU library, and the test-worker that collects this file is the
+one that loads it. The persistent compilation cache is off around these
+compiles — an entry written for a described chip cannot be read back
+without one.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        from jax.experimental import topologies
+
+        try:
+            yield topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("k", [8, 512])
+def test_scatter_kernel_compiles_with_mosaic(one_chip, k):
+    """The Pallas scatter kernel, compiled (``interpret=False``) at a real
+    (4096, 4096) f32 tile, for the smallest carrier bucket and one near
+    the VMEM budget's limit at this tile width."""
+    from spark_examples_tpu.ops.scatter_kernel import (
+        kernel_block_rows,
+        scatter_pairs_kernel,
+    )
+
+    assert kernel_block_rows(4096, 4096, k) is not None
+    compiled = (
+        jax.jit(lambda g, i: scatter_pairs_kernel(g, i, i, interpret=False))
+        .lower(
+            _spec((4096, 4096), jnp.float32, one_chip),
+            _spec((512, k), jnp.int32, one_chip),
+        )
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_finish_compiles_at_north_star(one_chip):
+    """The fused finish program (centering → panel-rank check →
+    CholeskyQR subspace eig → packed readback) at N=2504, k=2 with the
+    shipped sweep defaults."""
+    from spark_examples_tpu.ops.fused import (
+        _DEF_ITERS,
+        _DEF_OVERSAMPLE,
+        _finish_jit,
+    )
+
+    compiled = _finish_jit.lower(
+        _spec((2504, 2504), jnp.float32, one_chip),
+        2,
+        _DEF_OVERSAMPLE,
+        _DEF_ITERS,
+        _spec((2,), jnp.uint32, one_chip),
+    ).compile()
+    # ≥: the TPU layout pads the minor dimension to its tiling.
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 2504 * (2 + _DEF_OVERSAMPLE + 4) * 4
+
+
+def test_packed_int8_gramian_update_compiles(one_chip):
+    """One packed block update ``G += unpack(Xp) · unpack(Xp)ᵀ`` on the
+    integer MXU at (2504 samples, 8192 variants)."""
+    from spark_examples_tpu.ops.gramian import _gramian_accumulate_packed_jit
+
+    compiled = _gramian_accumulate_packed_jit.lower(
+        _spec((2504, 2504), jnp.float32, one_chip),
+        _spec((2504, 8192 // 8), jnp.uint8, one_chip),
+        n_bits=8192,
+        compute_dtype=np.dtype(np.int8),
+    ).compile()
+    assert compiled.memory_analysis().output_size_in_bytes >= 2504 * 2504 * 4
+
+
+@pytest.mark.parametrize("k", [64, 512])
+def test_sharded_scatter_kernel_compiles_on_2x2_mesh(topo, k):
+    """The same kernel inside the mesh engine's shard_map tile program
+    (``--pca-mode sparse --mesh-shape data:2,model:2``), (2048, 2048)
+    tiles of an N=4096 G: the kernel's output must carry the tile's
+    varying mesh axes, which interpret mode never checks."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from spark_examples_tpu.parallel.sharded import _sparse_tile_kernels
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("data", "model"))
+    scatter = _sparse_tile_kernels(
+        mesh, "data", "model", 4096, 2048, 2048, "float32", "int8",
+        "pallas", False,
+    )[0]
+    compiled = scatter.lower(
+        _spec((4096, 4096), jnp.float32, NamedSharding(mesh, P("data", "model"))),
+        _spec((8192, k), jnp.int32, NamedSharding(mesh, P(None, None))),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -11,10 +11,6 @@ Design constraints baked in:
 
 - **stdlib only** — must run on any dev box / CI image with no installs
   (the same bar scripts/validate_trace.py holds itself to);
-- **Python 3.10 compatible** — ``tomllib`` is 3.11+, so config loading
-  falls back to a deliberately tiny TOML-subset reader for the handful
-  of shapes ``[tool.graftlint]`` uses (string/bool scalars and string
-  arrays; nested ``[tool.graftlint.rules.<name>]`` tables);
 - **suppressions are data** — every pragma hit is counted per rule and
   shown in the summary, so silencing debt stays visible.
 """
@@ -183,53 +179,6 @@ def _excluded(rel: str, exclude: Sequence[str]) -> bool:
 # -- config ----------------------------------------------------------------
 
 
-def _mini_toml_table(text: str, table: str) -> Dict[str, Any]:
-    """Extract one TOML table (and its ``<table>.rules.*`` subtables)
-    without tomllib: the Python 3.10 fallback. Supports only the value
-    shapes [tool.graftlint] uses — quoted strings, booleans, and
-    (possibly multi-line) arrays of quoted strings."""
-    out: Dict[str, Any] = {}
-    current: Optional[Dict[str, Any]] = None
-    pending_key: Optional[str] = None
-    pending_items: List[str] = []
-    for raw in text.splitlines():
-        line = raw.strip()
-        if pending_key is not None:
-            pending_items += re.findall(r'"((?:[^"\\]|\\.)*)"', line)
-            if line.endswith("]"):
-                assert current is not None
-                current[pending_key] = pending_items
-                pending_key, pending_items = None, []
-            continue
-        if not line or line.startswith("#"):
-            continue
-        if line.startswith("["):
-            header = line.strip("[]").strip()
-            if header == table:
-                current = out
-            elif header.startswith(table + ".rules."):
-                name = header[len(table + ".rules.") :].strip("\"'")
-                current = out.setdefault("rules", {}).setdefault(name, {})
-            else:
-                current = None
-            continue
-        if current is None or "=" not in line:
-            continue
-        key, _, value = line.partition("=")
-        key, value = key.strip().strip('"'), value.strip()
-        if value.startswith("[") and not value.endswith("]"):
-            pending_key = key
-            pending_items = re.findall(r'"((?:[^"\\]|\\.)*)"', value)
-            continue
-        if value.startswith("["):
-            current[key] = re.findall(r'"((?:[^"\\]|\\.)*)"', value)
-        elif value in ("true", "false"):
-            current[key] = value == "true"
-        else:
-            current[key] = value.strip('"')
-    return out
-
-
 def load_config(root: str) -> Dict[str, Any]:
     """``[tool.graftlint]`` from ``<root>/pyproject.toml`` (or {})."""
     path = os.path.join(root, "pyproject.toml")
@@ -238,14 +187,9 @@ def load_config(root: str) -> Dict[str, Any]:
             text = f.read()
     except OSError:
         return {}
-    try:
-        import tomllib  # Python 3.11+
+    import tomllib
 
-        return (
-            tomllib.loads(text).get("tool", {}).get("graftlint", {}) or {}
-        )
-    except ImportError:
-        return _mini_toml_table(text, "tool.graftlint")
+    return tomllib.loads(text).get("tool", {}).get("graftlint", {}) or {}
 
 
 def find_root(start: str) -> str:
