@@ -1581,10 +1581,7 @@ def main():
 
     # The bench always collects its own telemetry (the per-stage
     # breakdown rides in the output JSON); files are written only when
-    # the BENCH_*_OUT env vars ask for them. Per-kernel AOT compile/cost
-    # recording is an EXTRA compilation inside the timed warm phase, so
-    # it runs only when artifacts were explicitly requested — default
-    # bench warm numbers stay comparable with pre-telemetry rounds.
+    # the BENCH_*_OUT env vars ask for them.
     outs = {
         "trace_out": os.environ.get("BENCH_TRACE_OUT") or None,
         "metrics_out": os.environ.get("BENCH_METRICS_OUT") or None,
@@ -1592,7 +1589,6 @@ def main():
     }
     with TelemetrySession(
         **outs,
-        xla_cost=any(outs.values()),
         command="bench",
         config={
             "samples": N_SAMPLES,

@@ -50,7 +50,18 @@ _INGEST_SPANS = {
     "ingest.slice",  # CSR pairs -> per-block windows
     "ingest.build",  # window -> packed block (native scatter / numpy)
     "ingest.pack",   # legacy densified-block host pack
-    "ingest.put",    # device staging inside the prefetch feed
+    "ingest.put",    # device staging inside the prefetch feed, until
+                     # the transfer is ready
+    "ingest.sidecar_open",  # one source's CSR sidecar load + check
+}
+
+# Device-feed span contract (arrays/feed.py, ops/sparse.py): every
+# `feed.<sub>` span must be one of these — the benchmark splits the
+# device's idle time into "starved by the feed" and "blocked in
+# dispatch" from exactly this set. Not `ingest.`: the union of ingest
+# spans must not count the dispatcher's waits.
+_FEED_SPANS = {
+    "feed.wait",  # the dispatching thread's wait for its next input
 }
 
 # Analysis job tier span contract (serving/): every `job.<sub>` span
@@ -210,6 +221,14 @@ def validate_trace(path: str) -> List[str]:
                 f"{where}: unknown ingest sub-phase span "
                 f"{ev['name']!r} (expected one of "
                 f"{sorted(_INGEST_SPANS)})"
+            )
+        elif (
+            ev["name"].startswith("feed.")
+            and ev["name"] not in _FEED_SPANS
+        ):
+            errors.append(
+                f"{where}: unknown device-feed span {ev['name']!r} "
+                f"(expected one of {sorted(_FEED_SPANS)})"
             )
         elif (
             ev["name"].startswith("job.")

@@ -17,6 +17,8 @@ from typing import Iterable, Iterator
 import jax
 import numpy as np
 
+from spark_examples_tpu import obs
+
 __all__ = ["device_prefetch"]
 
 _SENTINEL = object()
@@ -51,20 +53,27 @@ def device_prefetch(
         return False
 
     def produce() -> None:
-        from spark_examples_tpu import obs
-
+        put_bytes = obs.get_registry().counter(
+            "feed_put_bytes_total",
+            "Host bytes staged onto the device by the prefetch feed",
+        )
         try:
             for block in blocks:
                 if stop.is_set():
                     return
                 target = sharding if sharding is not None else device
                 arr = np.asarray(block)
+                # The span ends when the transfer has landed, not when
+                # device_put returns: this thread is off the dispatch
+                # path, and the consumer would wait for the same copy.
                 with obs.span("ingest.put", bytes=int(arr.nbytes)):
                     staged = (
                         jax.device_put(arr, target)
                         if target is not None
                         else jax.device_put(arr)
                     )
+                    staged.block_until_ready()
+                put_bytes.inc(arr.nbytes)
                 if not _put(staged):
                     return
         except BaseException as e:  # noqa: BLE001 — re-raised in consumer
@@ -76,7 +85,10 @@ def device_prefetch(
     t.start()
     try:
         while True:
-            item = q.get()
+            # The dispatching thread's wait for its next input; the last
+            # one finds the end of the stream.
+            with obs.span("feed.wait"):
+                item = q.get()
             if item is _SENTINEL:
                 if err:
                     raise err[0]

@@ -1839,11 +1839,16 @@ class JsonlSource:
 
     def _ensure_csr(self) -> _CsrCohort:
         if self._csr is None:
+            from spark_examples_tpu import obs
+
             with self._lazy_lock:
                 if self._csr is None:
-                    self._csr = _CsrCohort.load_or_build(
-                        self.root, self._open
-                    )
+                    # Once per source: the load (and CRC pass) of the
+                    # sidecar, inside the first shard's ingest.fetch.
+                    with obs.span("ingest.sidecar_open"):
+                        self._csr = _CsrCohort.load_or_build(
+                            self.root, self._open
+                        )
         return self._csr
 
     def ensure_sidecar(self) -> Optional[str]:

@@ -41,14 +41,14 @@ class TelemetrySession:
         config: Optional[Dict[str, Any]] = None,
         command: str = "",
         annotate_jax: bool = True,
-        xla_cost: bool = True,
+        xla_cost: bool = False,
         extra: Optional[Dict[str, Any]] = None,
     ) -> None:
-        """``xla_cost=False`` skips the per-kernel AOT lower+compile
-        cost recording (obs/xla.py) — it is one EXTRA compilation per
-        kernel signature, an observer effect workloads that time their
-        own warm phase (bench) only accept when artifacts were
-        explicitly requested."""
+        """``xla_cost`` is kept only so that callers which still pass
+        ``xla_cost=False`` run unchanged: the per-kernel XLA cost
+        recording it switched on is gone, so ``True`` is refused."""
+        if xla_cost:
+            raise ValueError("xla_cost: the XLA cost recording was removed")
         self.trace_out = trace_out
         self.metrics_out = metrics_out
         self.manifest_out = manifest_out
@@ -57,16 +57,12 @@ class TelemetrySession:
         self.extra: Dict[str, Any] = dict(extra or {})
         self.tracer = _tracer.SpanTracer(annotate_jax=annotate_jax)
         self.registry = _metrics.MetricsRegistry()
-        self.xla_cost = xla_cost
         self._root = None
 
     # -- lifecycle ----------------------------------------------------------
 
     def __enter__(self) -> "TelemetrySession":
         global _current
-        from spark_examples_tpu.obs import xla as _xla
-
-        _xla.reset(enabled=self.xla_cost)
         _tracer.set_tracer(self.tracer, active=True)
         _metrics.set_registry(self.registry)
         with _current_lock:

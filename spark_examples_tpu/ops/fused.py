@@ -244,7 +244,6 @@ def fused_finish(
     Returns ``(coords (N, k), vals (k,) float64, row_sums (N,))``.
     """
     from spark_examples_tpu import obs
-    from spark_examples_tpu.obs.xla import record_compiled
 
     n = int(g.shape[0])
     p = min(n, k + oversample)
@@ -252,9 +251,6 @@ def fused_finish(
     for attempt in range(max_retries + 1):
         run_iters = iters << attempt
         key = jax.random.PRNGKey(seed)
-        record_compiled(
-            "fused_finish", _finish_jit, gd, k, oversample, run_iters, key
-        )
         with obs.span(
             "fused_finish", n=n, k=k, iters=run_iters, attempt=attempt
         ):

@@ -138,9 +138,6 @@ def gramian(x, compute_dtype=None, accum_dtype=jnp.float32):
     compute_dtype = resolve_gramian_compute_dtype(
         x.dtype, accum_dtype, compute_dtype
     )
-    from spark_examples_tpu.obs.xla import record_compiled
-
-    record_compiled("gramian", _gramian_jit, x, compute_dtype, accum_dtype)
     return _gramian_jit(x, compute_dtype, accum_dtype)
 
 
@@ -338,7 +335,6 @@ def gramian_blockwise(
     """
     from spark_examples_tpu import obs
     from spark_examples_tpu.arrays.feed import device_prefetch
-    from spark_examples_tpu.obs.xla import record_compiled
 
     g = jnp.zeros((n_samples, n_samples), dtype=accum_dtype)
     if device is not None:
@@ -365,19 +361,9 @@ def gramian_blockwise(
                     packed_stream(), depth=prefetch_depth, device=device
                 )
             ):
-                if i == 0:
-                    record_compiled(
-                        "gramian_accumulate_packed",
-                        _gramian_accumulate_packed_jit,
-                        g,
-                        xp,
-                        8 * xp.shape[1],
-                        resolve_gramian_compute_dtype(
-                            jnp.int8, g.dtype, compute_dtype
-                        ),
-                    )
-                # One span per accumulation DISPATCH (async; ~µs): its
-                # start is the cold-stream acceptance anchor — the
+                # One span per accumulation DISPATCH (it times the
+                # enqueue, not the device): its start is the
+                # cold-stream acceptance anchor — the
                 # first accumulate must begin while later shards are
                 # still inside their ingest.fetch spans.
                 with obs.span("gramian.accumulate", block=i):
@@ -389,16 +375,6 @@ def gramian_blockwise(
         for i, xb in enumerate(
             device_prefetch(blocks, depth=prefetch_depth, device=device)
         ):
-            if i == 0:
-                record_compiled(
-                    "gramian_accumulate",
-                    _gramian_accumulate_jit,
-                    g,
-                    xb,
-                    resolve_gramian_compute_dtype(
-                        xb.dtype, g.dtype, compute_dtype
-                    ),
-                )
             with obs.span("gramian.accumulate", block=i):
                 g = gramian_accumulate(g, xb, compute_dtype=compute_dtype)
     return g

@@ -200,14 +200,11 @@ def pcoa(g, k, scale=False):
     Returns:
       ``(coords, eigvals)`` as in :func:`principal_components`.
 
-    The jitted body lives in ``_pcoa_jit``; this wrapper exists so the
-    telemetry session (when active) can record the kernel's compile time
-    and XLA cost analysis per call signature.
+    The jitted body lives in ``_pcoa_jit``; this wrapper times each call
+    as a ``pcoa`` span when a telemetry session is active.
     """
     from spark_examples_tpu import obs
-    from spark_examples_tpu.obs.xla import record_compiled
 
-    record_compiled("pcoa", _pcoa_jit, g, k, scale)
     with obs.span("pcoa", n=int(g.shape[0]), k=int(k)):
         return _pcoa_jit(g, k, scale)
 

@@ -376,8 +376,16 @@ def sparse_gramian_blockwise(
     scatter_path = resolve_scatter_path(
         (n_samples, n_samples), np.dtype(accum_dtype)
     )
+    windows = iter(windows)
     with obs.span("gramian.sparse.accumulate", n=n_samples):
-        for window_idx, lens in windows:
+        while True:
+            # The dispatching thread's wait for its next window; the
+            # last one finds the end of the stream.
+            with obs.span("feed.wait"):
+                window = next(windows, None)
+            if window is None:
+                break
+            window_idx, lens = window
             lens = np.asarray(lens)
             _check_indices(np.asarray(window_idx), n_samples)
             route = window_route(lens, n_samples, density_threshold)

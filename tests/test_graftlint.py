@@ -270,6 +270,18 @@ class TestSchemaSharing:
             "schema diverged — change both sides in one PR"
         )
 
+    def test_extracted_feed_spans_match_schema_exactly(self, project):
+        schema = span_contract_mod.load_schema(REPO_ROOT)
+        extracted = {
+            name
+            for name in span_contract_mod.extract_span_names(project)
+            if name.startswith("feed.")
+        }
+        assert extracted == set(schema._FEED_SPANS), (
+            "emitted feed.* span literals and the validate_trace "
+            "schema diverged — change both sides in one PR"
+        )
+
     def test_extracted_job_spans_match_schema_exactly(self, project):
         schema = span_contract_mod.load_schema(REPO_ROOT)
         extracted = {
@@ -411,6 +423,44 @@ class TestSchemaSharing:
         messages = "\n".join(f.message for f in findings)
         assert "gramian.sparse.typo" in messages
         assert "gramian.sparse.window" in messages
+
+
+    @pytest.mark.parametrize(
+        "emitted,drift",
+        [("feed.typo", True), ("feed.wait", False)],
+        ids=["positive", "negative"],
+    )
+    def test_feed_span_drift(self, tmp_path, emitted, drift):
+        """The device feed's feed.* family gets the same two-way drift
+        gate; a tree that emits exactly the schema's set is clean."""
+        pkg = tmp_path / "pkg"
+        pkg.mkdir()
+        (pkg / "feed.py").write_text(
+            "from spark_examples_tpu import obs\n\n\n"
+            "def pull(q):\n"
+            f"    with obs.span('{emitted}'):\n"
+            "        return q.get()\n"
+        )
+        scripts = tmp_path / "scripts"
+        scripts.mkdir()
+        (scripts / "validate_trace.py").write_text(
+            "_FEED_SPANS = {'feed.wait'}\n"
+        )
+        lines = ["[tool.graftlint]", "exclude = []"]
+        for name in ALL_RULE_NAMES:
+            lines.append(f'[tool.graftlint.rules."{name}"]')
+            enabled = name == "span-contract"
+            lines.append(f"enabled = {'true' if enabled else 'false'}")
+            if enabled:
+                lines.append('paths = ["pkg"]')
+        (tmp_path / "pyproject.toml").write_text("\n".join(lines) + "\n")
+        findings, _ = run_lint(str(tmp_path), [])
+        messages = "\n".join(f.message for f in findings)
+        if drift:
+            assert "feed.typo" in messages  # emitted-but-unknown
+            assert "feed.wait" in messages  # schema-but-unemitted
+        else:
+            assert findings == [], messages
 
 
 class TestEngineBehavior:

@@ -401,6 +401,15 @@ class TestTelemetrySession:
         err_key = 'genomics_rpc_errors_total{method="Op",transport="test"}'
         assert snap["counters"][err_key] == 1
 
+    def test_xla_cost_is_accepted_off_and_refused_on(self):
+        with TelemetrySession(xla_cost=False) as s:
+            with obs.span("stage"):
+                pass
+        names = [e["name"] for e in s.tracer.to_chrome()["traceEvents"]]
+        assert "stage" in names
+        with pytest.raises(ValueError, match="xla_cost"):
+            TelemetrySession(xla_cost=True)
+
 
 class TestPipelineManifestEmission:
     """The acceptance shape: a CPU-only CLI pca run with all three

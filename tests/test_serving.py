@@ -1571,17 +1571,12 @@ class TestIntrospectionPlane:
 class TestTraceReplayChaosPin:
     """PR 16 extension of the kill -9 chaos contract: the journal
     carries the admission-minted trace id, so a replayed job re-emits
-    ITS span timeline — same span names, same order (durations and
-    compile-cache artifacts may differ; ``xla_compile:*`` spans are
-    cache-state, not job semantics, and are excluded)."""
+    ITS span timeline — same span names, same order (durations may
+    differ)."""
 
     @staticmethod
     def _span_sequence(events):
-        return [
-            ev["name"]
-            for ev in events
-            if not ev["name"].startswith("xla_compile:")
-        ]
+        return [ev["name"] for ev in events]
 
     def test_replayed_job_reemits_same_span_sequence(
         self, served_source, tmp_path

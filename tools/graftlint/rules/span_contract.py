@@ -190,7 +190,7 @@ class SpanContractRule:
     name = NAME
     code = CODE
     summary = (
-        "spans are context-managed; ingest.*/job.*/gramian.sparse.*/"
+        "spans are context-managed; ingest.*/feed.*/job.*/gramian.sparse.*/"
         "gramian.sketch.*/pairhmm.* span names, pod.* instant names, "
         "and wire/ingest/serving/sparse/sketch metric registrations "
         "match scripts/validate_trace.py exactly"
@@ -221,13 +221,14 @@ class SpanContractRule:
                         )
         # 2-3. Name-set cross-check against the runtime schema — the
         # same closed-set discipline for each prefixed span family
-        # (ingest sub-phases, serving job tier).
+        # (ingest sub-phases, device feed, serving job tier).
         schema = load_schema(project.root)
         if schema is None:
             return findings
         span_names = extract_span_names(project)
         for prefix, attr in (
             ("ingest.", "_INGEST_SPANS"),
+            ("feed.", "_FEED_SPANS"),
             ("job.", "_JOB_SPANS"),
             ("gramian.sparse.", "_SPARSE_SPANS"),
             ("gramian.sketch.", "_SKETCH_SPANS"),
