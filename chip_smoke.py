@@ -16,7 +16,8 @@ at tiny sizes; only :func:`main` insists on a TPU):
 - ``sparse``: a seeded rare-variant cohort at N=4,096 through
   ``--pca-mode sparse``, so windows take the scatter route. G must equal
   the host X·Xᵀ exactly, and on the chip every scatter window must take
-  the compiled Pallas kernel.
+  the compiled Pallas kernel or the MXU update (as its shape decides),
+  none the scan.
 - ``mesh`` (``--chips 4``): the same structured cohort on
   ``--mesh-shape data:2,model:2``, sample-sharded dense and
   ``--pca-mode sparse``, plus the rare-variant cohort through the sharded
@@ -142,7 +143,7 @@ def _scatter_counts() -> dict:
     counter = obs.get_registry().counter("sparse_scatter_path_windows_total")
     return {
         p: counter.labels(path=p).value
-        for p in ("pallas", "interpret", "scan", "fallback-scan")
+        for p in ("pallas", "interpret", "scan", "mxu", "fallback-scan")
     }
 
 
@@ -204,9 +205,12 @@ def _finish_that_ran(timer) -> str:
     return "non-fused"
 
 
-def _run_sparse(name, source, argv, g_ref, clock, require_pallas, mesh=None,
-                n_devices=None):
-    """Sparse-engine ingest; G exact, scatter path named and counted."""
+def _run_sparse(name, source, argv, g_ref, clock, require_chip_path,
+                mesh=None, n_devices=None):
+    """Sparse-engine ingest; G exact, scatter path named and counted.
+    With ``require_chip_path``, every window took a path of the chip: the
+    compiled Pallas kernel, or (single device only) the MXU update —
+    never the scan, and never a fall to it."""
     from spark_examples_tpu.ops.scatter_kernel import resolve_scatter_path
 
     n = g_ref.shape[0]
@@ -240,10 +244,12 @@ def _run_sparse(name, source, argv, g_ref, clock, require_pallas, mesh=None,
          f"{c1[1] - c0[1]} compiles {c1[0] - c0[0]:.1f} s")
     if not windows:
         raise AssertionError(f"{name}: no window took the scatter route")
-    if require_pallas and (path != "pallas" or set(windows) != {"pallas"}):
+    allowed = {"pallas"} if mesh is not None else {"pallas", "mxu"}
+    if require_chip_path and not set(windows) <= allowed:
         raise AssertionError(
             f"{name}: expected every scatter window on the compiled Pallas "
-            f"kernel, got path {path} and windows {windows}"
+            f"kernel{'' if mesh is not None else ' or the MXU update'}, got "
+            f"path {path} and windows {windows}"
         )
     return {"path": path, "windows": windows}
 
@@ -257,17 +263,17 @@ def phase_pca(clock, n=PCA_SAMPLES, v=PCA_VARIANTS, seed=PCA_SEED):
     )
 
 
-def phase_sparse(clock, require_pallas, n=SPARSE_SAMPLES, v=SPARSE_VARIANTS,
-                 seed=SPARSE_SEED, af=SPARSE_AF):
+def phase_sparse(clock, require_chip_path, n=SPARSE_SAMPLES,
+                 v=SPARSE_VARIANTS, seed=SPARSE_SEED, af=SPARSE_AF):
     source = rare_cohort(n, v, seed, af)
     g_ref = host_gramian(source)
     return _run_sparse(
         "sparse", source, ["--pca-mode", "sparse"], g_ref, clock,
-        require_pallas,
+        require_chip_path,
     )
 
 
-def phase_mesh(clock, require_pallas, n=PCA_SAMPLES, v=PCA_VARIANTS,
+def phase_mesh(clock, require_chip_path, n=PCA_SAMPLES, v=PCA_VARIANTS,
                seed=PCA_SEED, sparse_n=SPARSE_SAMPLES,
                sparse_v=SPARSE_VARIANTS, mesh_shape=MESH_SHAPE,
                n_devices=MESH_DEVICES):
@@ -292,7 +298,7 @@ def phase_mesh(clock, require_pallas, n=PCA_SAMPLES, v=PCA_VARIANTS,
     out["mesh-scatter"] = _run_sparse(
         "mesh-scatter", rare,
         ["--mesh-shape", mesh_shape, "--pca-mode", "sparse"],
-        host_gramian(rare), clock, require_pallas, mesh=mesh,
+        host_gramian(rare), clock, require_chip_path, mesh=mesh,
         n_devices=n_devices,
     )
     return out
@@ -332,10 +338,10 @@ def main(argv=None) -> int:
     clock = _CompileClock()
     t0 = time.perf_counter()
     if args.chips == 4:
-        phase_mesh(clock, require_pallas=True)
+        phase_mesh(clock, require_chip_path=True)
     else:
         phase_pca(clock)
-        phase_sparse(clock, require_pallas=True)
+        phase_sparse(clock, require_chip_path=True)
     _say(f"smoke timing (not a metric): all phases "
          f"{time.perf_counter() - t0:.1f} s, {clock.count} compiles "
          f"{clock.seconds:.1f} s")

@@ -324,7 +324,7 @@ _LABELED_COUNTERS = {
     "sparse_gramian_windows_total": "route",  # scatter/dense per window
     "sparse_pod_coalesced_windows_total": "mode",  # gang/solo per step
     "sparse_scatter_path_windows_total": "path",  # pallas/interpret/scan/
-                                          # fallback-scan per window
+                                          # mxu/fallback-scan per window
     "sparse_pod_sync_total": "outcome",   # synced/drained/producer-error/
                                           # route-divergence/dtype-divergence
 }

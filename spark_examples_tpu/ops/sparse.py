@@ -11,22 +11,32 @@ indicator Gramian:
 
     G[i, j] += |{v : i ∈ carriers(v) and j ∈ carriers(v)}|
 
-accumulated as ONE scatter-add per window, directly from the
+accumulated by ONE device step per window, directly from the
 ``(indices, lens)`` CSR windows the ingest tier already produces
-(:func:`spark_examples_tpu.arrays.blocks.csr_windows`) — no densify, no
-bit-pack, no (N, V_blk) transient. Work is O(Σ k_v²) (k_v = carriers of
-variant v) instead of O(N²·V_blk): at density d the ratio is ~d², which
-is what makes the 98%-zeros regime tractable at all.
+(:func:`spark_examples_tpu.arrays.blocks.csr_windows`) — what crosses to
+the device is the window's carrier indices, never a densified or
+bit-packed block.
 
-Formulation (one-hot-free segment scatter): each window's ragged carrier
-lists are right-padded into a ``(V_blk, k_max)`` int32 index matrix with
-an out-of-range sentinel; the jitted kernel scatter-adds ``+1`` at every
-``(idx[v, a], idx[v, b])`` pair with OOB-drop semantics, so sentinel
-pairs vanish and the accumulation stays integer-exact (every update is
-an exact +1 in f32, the same exactness argument as the dense path —
-bit-identical G, pinned by tests). The scatter runs in fixed-size
-variant chunks under ``lax.scan`` so the update transient is bounded by
-``chunk · k_max²`` — never window-sized.
+Formulation: each window's ragged carrier lists are right-padded into a
+``(V_blk, k_max)`` int32 index matrix with an out-of-range sentinel. The
+device step (``_scatter_accumulate_jit``) then takes one of two forms,
+chosen per window on the host
+(:func:`spark_examples_tpu.ops.scatter_kernel.window_scatter_path`):
+
+- the pair scatter: ``+1`` at every ``(idx[v, a], idx[v, b])`` pair with
+  OOB-drop semantics, O(Σ k_v²) work (k_v = carriers of variant v), in
+  fixed-size variant chunks under ``lax.scan`` (or the Pallas one-hot
+  kernel) so the update transient is bounded by ``chunk · k_max²``;
+- the MXU update (single device, TPU, in place of both): the
+  window's ``(N, V_blk)`` int8 carrier-count panel is built on the
+  device by comparing the index matrix against a sample iota
+  (:func:`carrier_count_panel`), then ``G += X·Xᵀ`` runs on the int8
+  MXU — O(N²·V_blk) work on the unit built for it (on a TPU v5e, 1.4 ms
+  per 3,202 × 8,192 window at k_max = 64, where the scan takes 259 ms).
+
+Sentinel entries vanish and duplicate carriers count multiply in both,
+and every update is an exact integer in f32 (the same exactness argument
+as the dense path — bit-identical G, pinned by tests).
 
 Density routing: genuinely dense windows (common variants) would pay
 k_max² ≈ (dN)² per variant here while the MXU path pays N·V_blk — the
@@ -51,6 +61,7 @@ __all__ = [
     "DEFAULT_POD_COALESCE_VARIANTS",
     "DEFAULT_SPARSE_DENSITY_THRESHOLD",
     "SCATTER_CHUNK_VARIANTS",
+    "carrier_count_panel",
     "dense_panel_width",
     "padded_carrier_matrix",
     "scatter_pairs_chunked",
@@ -235,17 +246,58 @@ def scatter_pairs_chunked(g, row_idx, col_idx):
     return g
 
 
-@partial(jax.jit, donate_argnums=(0,), static_argnames=("path",))
-def _scatter_accumulate_jit(g, idx, path="scan"):
+def carrier_count_panel(idx, n_rows: int):
+    """``X[n, v] = |{a : idx[v, a] = n}|`` as an ``(n_rows, V_pad)`` int8
+    panel, built on the device from a ``(V_pad, k)`` carrier matrix.
+
+    A compare against a sample iota, summed over the carrier axis: no
+    scatter unit and no sort. Sentinel entries (≥ ``n_rows``) match no
+    row and drop out, as the scatter's ``mode="drop"`` does; a duplicate
+    carrier counts more than once, as scatter-add does — so ``X·Xᵀ`` is
+    the pair scatter's ``Σ_v r_v r_vᵀ`` exactly. A count is at most k,
+    which the dispatch keeps within int8
+    (:data:`spark_examples_tpu.ops.scatter_kernel.MXU_MAX_CARRIER_BUCKET`).
+
+    The sum is a chain of k elementwise adds that fuses into one loop: on
+    a TPU v5e it builds a 3,202 × 8,192 panel from k = 64 in 0.94 ms,
+    where a ``reduce`` over the broadcast (k, N, V) compare takes
+    1.47 ms and an ``.at[].add`` scatter of the V·k entries 4.42 ms.
+    """
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n_rows, idx.shape[0]), 0)
+    x = jnp.zeros(rows.shape, jnp.int8)
+    for carriers in idx.T:
+        x += (rows == carriers).astype(jnp.int8)
+    return x
+
+
+@partial(
+    jax.jit,
+    donate_argnums=(0,),
+    static_argnames=("path", "compute_dtype"),
+)
+def _scatter_accumulate_jit(g, idx, path="scan", compute_dtype=None):
     """``g[idx[v,a], idx[v,b]] += 1`` for every (v, a, b) — OOB dropped.
 
-    ``path`` is the pre-resolved scatter implementation
-    (:func:`spark_examples_tpu.ops.scatter_kernel.resolve_scatter_path`):
-    the chunked-scan body, or the Pallas one-hot-count kernel
-    (compiled / interpreter mode) — bit-identical either way.
+    ``path`` is the pre-resolved implementation
+    (:func:`spark_examples_tpu.ops.scatter_kernel.window_scatter_path`):
+    the chunked-scan body, the Pallas one-hot-count kernel (compiled /
+    interpreter mode), or ``"mxu"`` — the carrier-count panel built on
+    the device and contracted on the MXU in ``compute_dtype``
+    (:func:`spark_examples_tpu.ops.gramian.mxu_cross_product`) —
+    bit-identical whichever.
     """
     if path == "scan":
         return scatter_pairs_chunked(g, idx, idx)
+    if path == "mxu":
+        from spark_examples_tpu.ops.gramian import mxu_cross_product
+
+        # The barrier keeps the panel build out of the contraction's
+        # fusion: fused, the TPU recomputes the panel for every output
+        # tile (XLA's cost model: about 4× the two steps apart).
+        x = jax.lax.optimization_barrier(
+            carrier_count_panel(idx, g.shape[0])
+        )
+        return g + mxu_cross_product(x, g.dtype, compute_dtype)
     from spark_examples_tpu.ops.scatter_kernel import scatter_pairs_kernel
 
     return scatter_pairs_kernel(g, idx, idx, interpret=path == "interpret")
@@ -266,8 +318,10 @@ def sparse_gramian_accumulate(g, window_idx, lens, scatter_path=None):
     Returns the updated G. Bit-identical to densifying the window and
     running ``gramian_accumulate`` (pinned by tests). ``scatter_path``
     pre-resolves the scan-vs-Pallas-kernel choice for streams that
-    dispatch many windows (resolved per call here when ``None``).
+    dispatch many windows (resolved per call here when ``None``); on a
+    TPU a window whose bucket fits int8 takes the MXU update instead.
     """
+    from spark_examples_tpu.ops.gramian import resolve_gramian_compute_dtype
     from spark_examples_tpu.ops.scatter_kernel import (
         resolve_scatter_path,
         window_scatter_path,
@@ -281,8 +335,17 @@ def sparse_gramian_accumulate(g, window_idx, lens, scatter_path=None):
         sentinel=g.shape[0],
         n_rows=_pad_rows_for_scan(np.asarray(lens).size),
     )
-    path = window_scatter_path(scatter_path, g.shape, idx.shape[1])
-    return _scatter_accumulate_jit(g, idx, path=path)
+    path = window_scatter_path(
+        scatter_path, g.shape, idx.shape[1], single_device=True
+    )
+    compute_dtype = (
+        resolve_gramian_compute_dtype(jnp.int8, g.dtype)
+        if path == "mxu"
+        else None
+    )
+    return _scatter_accumulate_jit(
+        g, idx, path=path, compute_dtype=compute_dtype
+    )
 
 
 def _note_window(route: str, nnz: int, count: int = 1) -> None:

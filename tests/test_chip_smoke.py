@@ -1,7 +1,8 @@
 """chip_smoke.py's phases on the CPU at tiny sizes, and its refusals.
 
 The phases are the same functions the chip run calls; here the scatter
-path is the scan (no Mosaic backend), so ``require_pallas`` is off.
+path is the scan (no Mosaic backend, no MXU update off the TPU), so
+``require_chip_path`` is off.
 """
 
 import os
@@ -43,18 +44,22 @@ def test_pca_phase_fails_when_auto_falls_back(clock, monkeypatch):
 
 
 def test_sparse_phase_scatters_exactly(clock):
-    out = chip_smoke.phase_sparse(clock, require_pallas=False, n=1024, v=2048)
+    out = chip_smoke.phase_sparse(
+        clock, require_chip_path=False, n=1024, v=2048
+    )
     assert out["path"] == "scan" and out["windows"] == {"scan": 1}
 
 
 def test_sparse_phase_refuses_scan_where_pallas_is_required(clock):
     with pytest.raises(AssertionError, match="compiled Pallas kernel"):
-        chip_smoke.phase_sparse(clock, require_pallas=True, n=1024, v=2048)
+        chip_smoke.phase_sparse(
+            clock, require_chip_path=True, n=1024, v=2048
+        )
 
 
 def test_mesh_phase_uses_every_device(clock):
     out = chip_smoke.phase_mesh(
-        clock, require_pallas=False, n=96, v=1024, sparse_n=1024,
+        clock, require_chip_path=False, n=96, v=1024, sparse_n=1024,
         sparse_v=2048,
     )
     assert set(out) == {"mesh-dense", "mesh-sparse", "mesh-scatter"}

@@ -7,7 +7,9 @@ testable on the CPU container — plus the dispatcher's env kill switch /
 auto-resolution semantics and the end-to-end sparse-engine integration
 (``SPARK_EXAMPLES_TPU_SCATTER_KERNEL=interpret`` matches the dense
 reference through both the single-device and mesh-sharded
-accumulators).
+accumulators). The single-device MXU update (device-built carrier-count
+panel, int8 contraction) is pinned the same way, with its per-window
+selection rule.
 """
 
 import numpy as np
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 from spark_examples_tpu.arrays.blocks import csr_windows
 from spark_examples_tpu.ops.gramian import gramian
 from spark_examples_tpu.ops.scatter_kernel import (
+    MXU_MAX_CARRIER_BUCKET,
     kernel_block_rows,
     resolve_scatter_path,
     scatter_pairs_kernel,
@@ -25,6 +28,9 @@ from spark_examples_tpu.ops.scatter_kernel import (
 )
 from spark_examples_tpu.ops.sparse import (
     SCATTER_CHUNK_VARIANTS,
+    _pad_rows_for_scan,
+    _scatter_accumulate_jit,
+    padded_carrier_matrix,
     scatter_pairs_chunked,
     sparse_gramian_blockwise,
 )
@@ -161,6 +167,135 @@ class TestKernelBitIdentity:
         assert window_scatter_path("interpret", (64, 128), 8) == "interpret"
         for p in before:
             assert counter.labels(path=p).value == before[p] + 1
+
+
+def _mxu_case(case):
+    """(n, carrier matrix) for one MXU-update bit-identity case."""
+    n = 130  # not a multiple of 128: the Pallas kernel could not run
+    rng = np.random.default_rng(len(case))
+    v, k_max = {"k8": (256, 8), "k64": (256, 64)}.get(case, (300, 8))
+    lens = rng.integers(0, k_max + 1, size=v)
+    lens[0] = k_max
+    idx = np.concatenate(
+        [rng.choice(n, size=k, replace=False) for k in lens]
+    )
+    mat = padded_carrier_matrix(
+        idx, lens, sentinel=n, n_rows=_pad_rows_for_scan(v)
+    )
+    if case == "sentinel-rows":
+        mat[::3] = n
+    elif case == "duplicate":
+        mat[1, :5] = 7  # one carrier five times in one variant
+    return n, mat
+
+
+class TestMxuUpdate:
+    """The scatter route's MXU update: the carrier-count panel built on
+    the device, then G += X·Xᵀ — bit-identical to the pair scatter."""
+
+    @pytest.mark.parametrize(
+        "case", ["k8", "k64", "tail", "sentinel-rows", "duplicate"]
+    )
+    def test_matches_scan_and_numpy(self, case):
+        n, mat = _mxu_case(case)
+        if case == "tail":
+            assert mat.shape[0] > 300  # V_pad > V: all-sentinel tail
+        g0 = np.random.default_rng(1).integers(0, 9, (n, n))
+        g0 = g0.astype(np.float32)
+        scan = np.asarray(
+            _scatter_accumulate_jit(jnp.asarray(g0), jnp.asarray(mat))
+        )
+        mxu = np.asarray(
+            _scatter_accumulate_jit(
+                jnp.asarray(g0), jnp.asarray(mat), path="mxu"
+            )
+        )
+        np.testing.assert_array_equal(mxu, scan)
+        x = np.zeros((n + 1, mat.shape[0]), np.int64)
+        np.add.at(x, (mat, np.arange(mat.shape[0])[:, None]), 1)
+        want = g0 + (x[:n] @ x[:n].T).astype(np.float32)
+        np.testing.assert_array_equal(mxu, want)
+
+    @pytest.mark.parametrize(
+        "backend,n,k,stream,kill,want",
+        [
+            ("tpu", 3202, 64, "scan", False, "mxu"),
+            ("tpu", 3202, 8, "scan", False, "mxu"),
+            ("tpu", 2504, 32, "scan", False, "mxu"),
+            ("tpu", 3202, 127, "scan", False, "mxu"),
+            ("tpu", 49960, 8, "scan", False, "mxu"),
+            ("tpu", 49960, 32, "scan", False, "mxu"),
+            ("tpu", 64, 128, "scan", False, "scan"),
+            ("tpu", 3202, 128, "scan", False, "scan"),
+            ("tpu", 49960, 128, "scan", False, "scan"),
+            ("tpu", 4096, 8, "pallas", False, "mxu"),
+            ("tpu", 4096, 64, "pallas", False, "mxu"),
+            ("tpu", 4096, 128, "pallas", False, "pallas"),
+            ("cpu", 3202, 64, "scan", False, "scan"),
+            ("gpu", 3202, 64, "scan", False, "scan"),
+            ("cpu", 128, 8, "interpret", False, "interpret"),
+            ("tpu", 3202, 64, "scan", True, "scan"),
+            ("tpu", 4096, 8, "scan", True, "scan"),
+        ],
+    )
+    def test_window_path_follows_the_rule(
+        self, monkeypatch, backend, n, k, stream, kill, want
+    ):
+        """On a single TPU a window its stream would scan takes the MXU
+        update, whatever N, if its bucket's counts fit int8, and is
+        counted; so does a window its stream would run on the Pallas
+        kernel; off the TPU, and under the kill switch, the stream's
+        path; the mesh tiles never."""
+        import jax
+
+        from spark_examples_tpu import obs
+
+        assert MXU_MAX_CARRIER_BUCKET == 127
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if kill:
+            monkeypatch.setenv("SPARK_EXAMPLES_TPU_SCATTER_KERNEL", "0")
+        else:
+            monkeypatch.delenv(
+                "SPARK_EXAMPLES_TPU_SCATTER_KERNEL", raising=False
+            )
+        counter = obs.get_registry().counter(
+            "sparse_scatter_path_windows_total"
+        )
+        before = counter.labels(path=want).value
+        assert (
+            window_scatter_path(stream, (n, n), k, single_device=True)
+            == want
+        )
+        assert counter.labels(path=want).value == before + 1
+        assert window_scatter_path(stream, (n, n), k) == stream
+
+    def test_engine_on_tpu_rule_matches_dense(self, monkeypatch):
+        """sparse_gramian_blockwise with the backend test seeing a TPU:
+        every scatter window takes the MXU update (counted), and G
+        equals the dense reference."""
+        import jax
+
+        from spark_examples_tpu import obs
+
+        n = 130
+        x, pair = cohort_csr(n, 300, density=0.015, seed=13)
+        want = np.asarray(gramian(x))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv(
+            "SPARK_EXAMPLES_TPU_SCATTER_KERNEL", raising=False
+        )
+        counter = obs.get_registry().counter(
+            "sparse_scatter_path_windows_total"
+        )
+        before = counter.labels(path="mxu").value
+        windows = list(csr_windows(iter([pair]), 64))
+        got = np.asarray(
+            sparse_gramian_blockwise(
+                iter(windows), n, density_threshold=0.5, block_variants=64
+            )
+        )
+        np.testing.assert_array_equal(got, want)
+        assert counter.labels(path="mxu").value == before + len(windows)
 
 
 class TestDispatcher:

@@ -110,6 +110,22 @@ def test_packed_int8_gramian_update_compiles(one_chip):
     assert compiled.memory_analysis().output_size_in_bytes >= 2504 * 2504 * 4
 
 
+def test_scatter_route_mxu_update_compiles(one_chip):
+    """The scatter route's MXU update at a rare-variant window of
+    (3,202 samples, 8,192 carrier rows, bucket 64): the carrier-count
+    panel built on the device, then the int8 contraction."""
+    from spark_examples_tpu.ops.sparse import _scatter_accumulate_jit
+
+    compiled = _scatter_accumulate_jit.lower(
+        _spec((3202, 3202), jnp.float32, one_chip),
+        _spec((8192, 64), jnp.int32, one_chip),
+        path="mxu",
+        compute_dtype=jnp.int8,
+    ).compile()
+    assert "convolution" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes >= 3202 * 3202 * 4
+
+
 @pytest.mark.parametrize("k", [64, 512])
 def test_sharded_scatter_kernel_compiles_on_2x2_mesh(topo, k):
     """The same kernel inside the mesh engine's shard_map tile program

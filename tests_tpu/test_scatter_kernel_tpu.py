@@ -75,8 +75,9 @@ class TestScatterKernelOnHardware:
 
     def test_sparse_blockwise_engine_matches_scan_fallback(self, tpu):
         """End to end through sparse_gramian_blockwise: the
-        auto-selected hardware kernel stream vs the same stream under
-        the SPARK_EXAMPLES_TPU_SCATTER_KERNEL=0 kill switch must be
+        auto-selected hardware stream (each window on the MXU update or
+        the kernel, as its shape decides) vs the same stream under the
+        SPARK_EXAMPLES_TPU_SCATTER_KERNEL=0 kill switch must be
         bit-identical (mixed scatter/dense routing included)."""
         import os
 
@@ -105,3 +106,47 @@ class TestScatterKernelOnHardware:
             else:
                 os.environ["SPARK_EXAMPLES_TPU_SCATTER_KERNEL"] = prev
         np.testing.assert_array_equal(auto, scan)
+
+    def test_mxu_update_is_selected_and_bit_exact(self, tpu):
+        """At N=3,202 (not lane-aligned, so no Pallas kernel) the dispatch
+        must send a 64-carrier-bucket window to the MXU update on the
+        chip, and its G must equal the scan path's and numpy's exactly
+        — sentinels, a tail of all-sentinel rows and duplicates
+        included."""
+        import jax.numpy as jnp
+
+        from spark_examples_tpu.ops.scatter_kernel import (
+            resolve_scatter_path,
+            window_scatter_path,
+        )
+        from spark_examples_tpu.ops.sparse import (
+            _pad_rows_for_scan,
+            _scatter_accumulate_jit,
+            padded_carrier_matrix,
+        )
+
+        n = 3202
+        idx, lens = _window(n, 2000, 0.012, seed=4)
+        mat = padded_carrier_matrix(
+            idx, lens, sentinel=n, n_rows=_pad_rows_for_scan(lens.size)
+        )
+        assert mat.shape[1] == 64 and mat.shape[0] > lens.size
+        mat[0, :3] = mat[0, 0]  # a duplicate carrier within one variant
+        stream = resolve_scatter_path((n, n), np.dtype(np.float32))
+        assert stream == "scan"
+        path = window_scatter_path(stream, (n, n), 64, single_device=True)
+        assert path == "mxu"
+        scan = np.asarray(
+            _scatter_accumulate_jit(jnp.zeros((n, n), jnp.float32), mat)
+        )
+        mxu = np.asarray(
+            _scatter_accumulate_jit(
+                jnp.zeros((n, n), jnp.float32), mat, path="mxu"
+            )
+        )
+        np.testing.assert_array_equal(scan, mxu)
+        x = np.zeros((n + 1, mat.shape[0]), dtype=np.int64)
+        np.add.at(x, (mat, np.arange(mat.shape[0])[:, None]), 1)
+        np.testing.assert_array_equal(
+            mxu, (x[:n] @ x[:n].T).astype(np.float32)
+        )
