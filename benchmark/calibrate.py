@@ -13,6 +13,9 @@ JSON line with
   lower (``benchmark/reference.py``: G in bfloat16, and coordinates from
   G and the centred matrix held in bfloat16), the upper readings.
 
+The reference is the one run.py compares with: G tiled over the cell's
+chips, and above ``reference.DENSE_LIMIT`` samples the iterative top-k.
+
 Benchmark runs do not run this.
 """
 
@@ -38,8 +41,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     args = ap.parse_args(argv)
     cell = run.resolve_cell(run.ROOT, args.workload)
-    if run.require_devices(cell.chips) is None:
+    devices = run.require_devices(cell.chips)
+    if devices is None:
         return 2
+    devices = devices[:cell.chips]
     run.use_compile_cache(run.ROOT)
     import numpy as np
 
@@ -57,13 +62,13 @@ def main(argv=None) -> int:
             g = np.asarray(g)
         finally:
             shutil.rmtree(work, ignore_errors=True)
-        g_ref = reference.gramian(cohort)
-        c_ref = reference.coordinates(g_ref, k)
+        g_ref, c_ref = run.reference_answers(cohort, k, devices)
         program = run.compare(cohort, [(g, coords, rows)], k,
                               cell.config["limits"], (g_ref, c_ref))
+        del g
         control = {
-            "g_err": float(np.abs(reference.control_gramian(g_ref)
-                                  - g_ref).max()),
+            "g_err": reference.g_err(reference.control_gramian(g_ref),
+                                     g_ref),
             "coord_err": reference.coord_err(
                 reference.control_coordinates(g_ref, k)[:, :2],
                 c_ref[:, :2]),

@@ -1,6 +1,7 @@
 """Share of the finish's roofline: the least time for the top-k
-eigenvectors of the centred G (``benchmark/work.py:finish_least``) over
-the device time of the finish executables in the trace, per analysis."""
+eigenvectors of the centred G (``benchmark/work.py:finish_least``), per
+chip, over the device time of the finish executables in the trace
+averaged over the chips, per analysis."""
 
 from benchmark.work import finish_least
 
@@ -15,7 +16,7 @@ KERNELS = ("_finish_jit", "_pcoa_jit")
 
 
 def _least(obs):
-    return finish_least(obs.work["n"], obs.work["k"], obs.peaks)
+    return finish_least(obs.work["n"], obs.work["k"], obs.peaks, obs.chips)
 
 
 def read(obs):

@@ -1,7 +1,8 @@
 """Share of the Gramian's roofline: the least time the chip needs for the
 cohort's G (``benchmark/work.py:gramian_least``, the same count whatever
-route or kernel does the work) over the device time of the accumulate
-executables in the trace, per analysis."""
+route or kernel does the work), per chip, over the device time of the
+accumulate executables in the trace averaged over the chips, per
+analysis."""
 
 from benchmark.work import gramian_least
 
@@ -21,7 +22,8 @@ KERNELS = (
 
 def _least(obs):
     w = obs.work
-    return gramian_least(w["n"], w["v"], w["nnz"], w["sum_k2"], obs.peaks)
+    return gramian_least(w["n"], w["v"], w["nnz"], w["sum_k2"], obs.peaks,
+                         obs.chips)
 
 
 def read(obs):

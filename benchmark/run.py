@@ -13,7 +13,9 @@ built from the CLI's own parser (``ingest_gramian`` then ``compute_pca``),
 until ``--seconds`` have passed, and finishes the analysis in flight.
 
 After the window every analysis's Gramian and coordinates are compared
-with the plain reference (``benchmark/reference.py``). The last line of
+with the plain reference (``benchmark/reference.py``), as far as the host's
+budget for them reaches (``keep_count``); past it, the first analyses'
+and the last's. The last line of
 stdout is the result; the last lines of stderr are the numbers compared,
 each with its limit. ``--trace 1`` records the window with the profiler
 and the program's spans and reports the cell's per-layer metrics
@@ -226,6 +228,7 @@ class Observed:
     peaks: dict
     spans: List[tuple] = field(default_factory=list)  # (name, t0, t1, tid)
     trace: object = None  # benchmark.trace.Trace
+    chips: int = 1  # the cell's; device times are averaged over them
 
 
 def _session_spans(session) -> List[tuple]:
@@ -243,26 +246,60 @@ def _work(cohort, cell) -> dict:
             "sum_k2": cohort.sum_k2(), "k": int(cell.config["num_pc"])}
 
 
+def peak_memory(devices) -> int:
+    """The largest ``peak_bytes_in_use`` over the devices (0 where the
+    backend reports none)."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
+
+
+# The host's budget for the Gramians the window keeps for the comparison,
+# 4 N^2 bytes each: every analysis of today's cells (at most about 340
+# Gramians of 41 MB), the first and the last at N = 49,960 (10 GB each).
+HOST_KEEP_BYTES = 16 << 30
+
+
+def keep_count(n_samples: int) -> int:
+    """How many analyses' answers the window keeps: the first ones, while
+    their float32 Gramians fit in ``HOST_KEEP_BYTES``, and the last."""
+    return max(2, HOST_KEEP_BYTES // (4 * n_samples * n_samples))
+
+
 # -- correctness -------------------------------------------------------------
 
 
+def reference_answers(cohort, num_pc: int, devices=None) -> tuple:
+    """The plain reference's (G, coordinates), G tiled over ``devices``."""
+    t0 = time.perf_counter()
+    g_ref = reference.gramian(cohort, devices)
+    t1 = time.perf_counter()
+    c_ref = reference.coordinates(g_ref, num_pc)
+    _say(f"reference: G in {t1 - t0:.2f} s, coordinates in "
+         f"{time.perf_counter() - t1:.2f} s")
+    return g_ref, c_ref
+
+
 def compare(cohort, results, num_pc: int, limits: dict,
-            answers=None) -> dict:
+            answers=None, devices=None) -> dict:
     """Each analysis's G and coordinates against the plain reference's
-    ``answers`` (G, coordinates), computed here when not given."""
+    ``answers`` (G, coordinates), computed here on ``devices`` when not
+    given. G_ref is put in the analyses' row order once (they share it)
+    and compared a row block at a time, never as an N x N float64."""
     import numpy as np
 
     if answers is None:
-        g_ref = reference.gramian(cohort)
-        answers = g_ref, reference.coordinates(g_ref, num_pc)
+        answers = reference_answers(cohort, num_pc, devices)
     g_ref, c_ref = answers
     order = {cid: i for i, cid in enumerate(cohort.sample_ids)}
+    wants = {}  # G_ref in each row order the analyses use
     g_err = coord_err = 0.0
     for g, coords, rows in results:
-        perm = [order[cid] for cid, _ in sorted(rows.items(),
-                                                 key=lambda kv: kv[1])]
-        want = g_ref[perm][:, perm]
-        g_err = max(g_err, float(np.abs(g.astype(np.float64) - want).max()))
+        perm = np.array([order[cid] for cid, _ in sorted(
+            rows.items(), key=lambda kv: kv[1])])
+        key = perm.tobytes()
+        if key not in wants:
+            wants[key] = reference.permuted(g_ref, perm)
+        g_err = max(g_err, reference.g_err(g, wants[key]))
         got = np.zeros((len(order), 2))
         for cid, pc1, pc2 in coords:
             got[order[cid]] = (pc1, pc2)
@@ -302,7 +339,8 @@ def main(argv=None, root: str = ROOT) -> int:
     from benchmark.peaks import peaks_for
 
     peaks = peaks_for(devices[0].device_kind)
-    _say(f"compile cache: {use_compile_cache(root)}")
+    _say(f"compile cache: {use_compile_cache(root)}; JAX up at "
+         f"{time.perf_counter() - T_START:.2f} s")
     clock = CompileCounter()
 
     cohort_cfg = cell.config["cohort"]
@@ -317,7 +355,9 @@ def main(argv=None, root: str = ROOT) -> int:
     work_dir = tempfile.mkdtemp(prefix="benchmark-cohort-")
     try:
         cohort_dir = os.path.join(work_dir, "cohort")
+        t0 = time.perf_counter()
         write_light_mirror(cohort, cohort_dir)
+        _say(f"setup: mirror written in {time.perf_counter() - t0:.2f} s")
         return _measure(args, root, cell, cohort, cohort_dir, devices,
                         peaks, clock)
     finally:
@@ -331,10 +371,11 @@ def _measure(args, root, cell, cohort, cohort_dir, devices, peaks,
 
     flags = list(cell.traffic["flags"])
     refs = cohort.references()
-    c0 = clock.count
+    c0, t0 = clock.count, time.perf_counter()
     analysis(cohort_dir, refs, flags)
-    _say(f"setup: warm analysis done, {clock.count - c0} compiles "
-         f"({clock.seconds:.2f} s compiling in set-up)")
+    _say(f"setup: warm analysis done in {time.perf_counter() - t0:.2f} s, "
+         f"{clock.count - c0} compiles ({clock.seconds:.2f} s compiling in "
+         "set-up)")
 
     session = log_dir = None
     if args.trace:
@@ -347,13 +388,15 @@ def _measure(args, root, cell, cohort, cohort_dir, devices, peaks,
         options.python_tracer_level = 0  # no per-call Python events
         options.host_tracer_level = 2
         jax.profiler.start_trace(log_dir, profiler_options=options)
+    room = keep_count(cohort.n_samples)
     routes0 = route_counts()
     io0 = host_io()
     compiles0 = clock.count
     setup_s = time.perf_counter() - T_START
-    # Every analysis's answers are kept for the comparison. Each G starts
-    # its copy to the host as soon as it is done and leaves the device
-    # when the next analysis is, so the window holds one extra G at most.
+    # The answers of the first ``room - 1`` analyses and of the last are
+    # kept for the comparison. Each of the first starts its copy to the host
+    # as soon as it is done, and every G leaves the device when the next
+    # analysis is, so the window holds one extra G at most.
     results, attempted, failed, ends, last = [], 0, 0, [], None
     w0 = time.perf_counter()
     from benchmark.trace import WINDOW
@@ -363,8 +406,9 @@ def _measure(args, root, cell, cohort, cohort_dir, devices, peaks,
             attempted += 1
             try:
                 g, coords, rows = analysis(cohort_dir, refs, flags)
-                g.copy_to_host_async()
-                if last is not None:
+                if len(results) + (last is not None) < room - 1:
+                    g.copy_to_host_async()
+                if last is not None and len(results) < room - 1:
                     results.append((np.asarray(last[0]),) + last[1:])
                 last = (g, coords, rows)
             except Exception as e:  # noqa: BLE001 — counted, run goes on
@@ -377,6 +421,7 @@ def _measure(args, root, cell, cohort, cohort_dir, devices, peaks,
     if last is not None:
         results.append((np.asarray(last[0]),) + last[1:])
     del last
+    done = attempted - failed  # analyses completed in the window
     io = {k: v - io0.get(k, 0) for k, v in host_io().items()}
     compiles = clock.count - compiles0
     routes = {k: v - routes0.get(k, 0.0) for k, v in route_counts().items()}
@@ -386,14 +431,13 @@ def _measure(args, root, cell, cohort, cohort_dir, devices, peaks,
         jax.profiler.stop_trace()
         spans = _session_spans(session)
         session.__exit__(None, None, None)
-    stats = devices[0].memory_stats() or {}
     device = {
         "platform": devices[0].platform,
         "kind": devices[0].device_kind,
         "count": len(jax.devices()),
-        "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0)),
+        "memory_peak_bytes": peak_memory(devices[:cell.chips]),
     }
-    _say(f"window: {len(results)} analyses in {window_s:.3f} s, "
+    _say(f"window: {done} analyses in {window_s:.3f} s, "
          f"{compiles} compiles; windows by route and scatter path: {routes}")
     _say(f"window: host reads and page faults {io}")
     _say("window: seconds per analysis " + " ".join(
@@ -401,7 +445,7 @@ def _measure(args, root, cell, cohort, cohort_dir, devices, peaks,
 
     t_ref = time.perf_counter()
     checks = compare(cohort, results, int(cell.config["num_pc"]),
-                     cell.config["limits"])
+                     cell.config["limits"], devices=devices[:cell.chips])
     _say(f"reference: compared in {time.perf_counter() - t_ref:.2f} s")
     correct = failed == 0 and checks_pass(checks)
 
@@ -415,9 +459,9 @@ def _measure(args, root, cell, cohort, cohort_dir, devices, peaks,
         device["busy_s"] = trace_mod.busy_seconds(trace_obj)
         device["window_s"] = trace_obj.window_s
         observed = Observed(
-            analyses=len(results), compiles=compiles,
+            analyses=done, compiles=compiles,
             work=_work(cohort, cell), peaks=peaks, spans=spans,
-            trace=trace_obj,
+            trace=trace_obj, chips=cell.chips,
         )
         metrics = {}
         for m in cell.per_layer:
@@ -434,7 +478,7 @@ def _measure(args, root, cell, cohort, cohort_dir, devices, peaks,
         }
     else:
         values = {
-            "analysis_s": window_s / max(len(results), 1),
+            "analysis_s": window_s / max(done, 1),
             "setup_s": setup_s,
         }
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}

@@ -18,7 +18,8 @@ def _generate(name, sites, shrink=1, max_carriers=64, seed=SEED):
         bh.tiny_cohort(cfg, sites, shrink, max_carriers), seed)
 
 
-@pytest.mark.parametrize("name", ["kgp3_chr20", "nygc_chr20_rare"])
+@pytest.mark.parametrize("name", ["kgp3_chr20", "nygc_chr20_rare",
+                                  "nygc_chr20_rare_full"])
 def test_same_seed_same_cohort_other_seed_other_carriers(name):
     a = _generate(name, 4000, shrink=4)
     b = _generate(name, 4000, shrink=4)
@@ -30,7 +31,8 @@ def test_same_seed_same_cohort_other_seed_other_carriers(name):
 
 
 @pytest.mark.parametrize("name,n", [("kgp3_chr20", 2504),
-                                    ("nygc_chr20_rare", 3202)])
+                                    ("nygc_chr20_rare", 3202),
+                                    ("nygc_chr20_rare_full", 3202)])
 def test_stated_sizes(name, n):
     cfg = bh.load_config(name)
     pops = dict(cfg["cohort"]["populations"])

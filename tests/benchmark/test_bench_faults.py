@@ -1,7 +1,7 @@
 """The comparison catches a broken timed path: each fault the cells can
 have is planted under run.py (chip check stubbed, CPU, tiny size) and the
 run has to come out not correct. The exchange between chips has no fault
-here: both cells run on one chip."""
+here: every cell runs on one chip."""
 
 import importlib
 
@@ -64,7 +64,8 @@ def _plant(monkeypatch, fault):
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
                                    "answer_altered"])
 @pytest.mark.parametrize("workload", ["kgp3_chr20.auto",
-                                      "nygc_chr20_rare.sparse"])
+                                      "nygc_chr20_rare.sparse",
+                                      "nygc_chr20_rare_full.auto"])
 def test_planted_fault_is_not_correct(tmp_path, monkeypatch, capsys,
                                       workload, fault):
     bh.stub_chip(monkeypatch)

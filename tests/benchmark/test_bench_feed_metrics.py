@@ -132,7 +132,8 @@ def test_recorded_trace_without_the_feed_spans(recorded):
     assert blocked * obs.analyses <= idle_s
 
 
-@pytest.mark.parametrize("cell", ["kgp3_chr20.auto", "nygc_chr20_rare.sparse"])
+@pytest.mark.parametrize("cell", ["kgp3_chr20.auto", "nygc_chr20_rare.sparse",
+                                  "nygc_chr20_rare_full.auto"])
 def test_cpu_traced_run_leaves_the_device_readers_out(cell, tmp_path,
                                                       monkeypatch, capsys):
     """The CPU has no device plane: both readers find nothing there, and

@@ -21,7 +21,8 @@ def _run(root, workload, trace, capsys, seconds="0.3"):
 
 
 @pytest.mark.parametrize("workload", ["kgp3_chr20.auto",
-                                      "nygc_chr20_rare.sparse"])
+                                      "nygc_chr20_rare.sparse",
+                                      "nygc_chr20_rare_full.auto"])
 def test_cell_runs_end_to_end_and_prints_the_last_line(
         tmp_path, monkeypatch, capsys, workload):
     bh.stub_chip(monkeypatch)

@@ -121,7 +121,8 @@ def test_metrics_and_their_readers(spec):
 
 
 @pytest.mark.parametrize("workload", ["kgp3_chr20.auto",
-                                      "nygc_chr20_rare.sparse"])
+                                      "nygc_chr20_rare.sparse",
+                                      "nygc_chr20_rare_full.auto"])
 def test_every_cell_reports_what_the_contract_asks(spec, workload):
     cell = run.resolve_cell(bh.REPO, workload)
     names = {m["name"] for m in cell.end_to_end}

@@ -101,3 +101,47 @@ def test_missing_window_is_an_error(tmp_path, recorded):
             trace.load(path)
         finally:
             trace.WINDOW = original
+
+
+def _roofline(name, tr, chips, work):
+    from benchmark import run
+    from benchmark.peaks import DEVICE_PEAKS
+
+    obs = run.Observed(analyses=2, compiles=0, work=work,
+                       peaks=DEVICE_PEAKS["TPU v5 lite"], trace=tr,
+                       chips=chips)
+    return run.load_module(bh.REPO, "metrics", name).read(obs)
+
+
+WORK = {"n": 2504, "v": 40000, "nnz": 4_960_000, "sum_k2": 5_520_000_000,
+        "k": 2}
+
+
+@pytest.mark.parametrize("name,want", [
+    # Read with the one-chip formula before the per-chip least time.
+    ("gramian_roofline", 2.577406827599223),
+    ("finish_roofline", 1.733309151106175),
+])
+def test_one_chip_roofline_on_the_recorded_trace_is_unchanged(recorded, name,
+                                                              want):
+    assert _roofline(name, recorded, 1, WORK) == want
+
+
+@pytest.mark.parametrize("name,kernel", [
+    ("gramian_roofline", "jit__gramian_accumulate_packed_jit(3)"),
+    ("finish_roofline", "jit__finish_jit(7)"),
+])
+def test_four_planes_read_the_share_of_one(name, kernel):
+    """The same work split over four chips, each running its quarter in a
+    quarter of the time, reads the share one chip reads doing it all."""
+    def planes(count, seconds):
+        return {f"/device:TPU:{i}": [(kernel, 1.0, 1.0 + seconds)]
+                for i in range(count)}
+
+    one = trace.Trace(window=(0.0, 10.0), modules=planes(1, 0.8))
+    four = trace.Trace(window=(0.0, 10.0), modules=planes(4, 0.2))
+    share = _roofline(name, one, 1, WORK)
+    assert share is not None and share > 0.0
+    assert _roofline(name, four, 4, WORK) == pytest.approx(share, rel=1e-12)
+    # Read as one chip, the four planes' share would be four times too high.
+    assert _roofline(name, four, 1, WORK) == pytest.approx(4 * share)

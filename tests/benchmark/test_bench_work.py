@@ -49,3 +49,30 @@ def test_kgp3_scale_gramian_least_on_v5e():
 def test_unknown_device_is_an_error():
     with pytest.raises(KeyError, match="no published peaks"):
         peaks_for("TPU v99")
+
+
+@pytest.mark.parametrize("chips", [2, 4])
+def test_least_time_is_per_chip(chips):
+    """The whole work over chips times one chip's peaks; the work itself
+    and which peak bounds it do not change."""
+    one = work.gramian_least(2504, 1812841, 225_000_000, 250_000_000_000,
+                             V5E)
+    many = work.gramian_least(2504, 1812841, 225_000_000, 250_000_000_000,
+                              V5E, chips=chips)
+    assert (many["ops"], many["bytes"], many["bound"]) == (
+        one["ops"], one["bytes"], one["bound"])
+    assert many["seconds"] == pytest.approx(one["seconds"] / chips)
+    fin = work.finish_least(49960, 2, V5E, chips=chips)
+    assert fin["seconds"] == pytest.approx(4.0 * 49960**2 / (chips * 819e9))
+
+
+def test_one_chip_least_time_is_unchanged_to_the_digit():
+    peaks = {"int8_ops": 393e12, "hbm_bytes_per_s": 819e9,
+             "bf16_flops": 197e12}
+    least = work.gramian_least(3202, 217244, 2930749, 91_000_000, peaks)
+    assert least == work.gramian_least(3202, 217244, 2930749, 91_000_000,
+                                       peaks, chips=1)
+    assert least["seconds"] == max(2.0 * 91_000_000 / 393e12,
+                                   (4.0 * 2930749 + 4.0 * 3202**2) / 819e9)
+    assert work.finish_least(3202, 2, peaks)["seconds"] == (
+        4.0 * 3202**2 / 819e9)
