@@ -536,7 +536,7 @@ def _sketch_scale_leg(carriers: int, block_v: int, mesh):
     n_variants = int(os.environ.get("BENCH_SCALE_SKETCH_V", 2048))
     power_iters = int(os.environ.get("BENCH_SCALE_SKETCH_POWER", 0))
     repeat = int(os.environ.get("BENCH_SCALE_REPEAT", 2))
-    bound = 4 << 30  # models.pca max_host_bytes / SKETCH_AUTO_G_BYTES
+    bound = 4 << 30  # models.pca max_device_bytes / SKETCH_AUTO_G_BYTES
     sweep = []
     for i, n in enumerate(ns):
         rng = np.random.default_rng(1000 + i)

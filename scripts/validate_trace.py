@@ -94,6 +94,15 @@ _SPARSE_SPANS = {
                                   # gang pull + exchanges + payload)
 }
 
+# Mesh finish span contract (parallel/sharded.sharded_pcoa): every
+# `finish.<sub>` span must be one of these — the benchmark's mesh cell
+# reads the finish's centring and eigensolve from exactly this set.
+_FINISH_SPANS = {
+    "finish.mesh.center",  # double-centring the sharded Gramian
+    "finish.mesh.eig",     # the subspace eigensolve (iterations and the
+                           # final residual in args)
+}
+
 # Gramian-free sketch engine span contract (ops/sketch.py + the mesh
 # half in parallel/sharded.py): every `gramian.sketch.<sub>` span must
 # be one of these — the million-sample-trajectory captures attribute
@@ -248,6 +257,14 @@ def validate_trace(path: str) -> List[str]:
                 f"{sorted(_SPARSE_SPANS)})"
             )
         elif (
+            ev["name"].startswith("finish.")
+            and ev["name"] not in _FINISH_SPANS
+        ):
+            errors.append(
+                f"{where}: unknown finish span {ev['name']!r} "
+                f"(expected one of {sorted(_FINISH_SPANS)})"
+            )
+        elif (
             ev["name"].startswith("gramian.sketch.")
             and ev["name"] not in _SKETCH_SPANS
         ):
@@ -324,7 +341,8 @@ _LABELED_COUNTERS = {
     "sparse_gramian_windows_total": "route",  # scatter/dense per window
     "sparse_pod_coalesced_windows_total": "mode",  # gang/solo per step
     "sparse_scatter_path_windows_total": "path",  # pallas/interpret/scan/
-                                          # mxu/fallback-scan per window
+                                          # mxu/mxu-tile/fallback-scan
+                                          # per window
     "sparse_pod_sync_total": "outcome",   # synced/drained/producer-error/
                                           # route-divergence/dtype-divergence
 }

@@ -178,12 +178,8 @@ def _cmd_pca(args) -> int:
         conf.variant_set_ids = [DEFAULT_VARIANT_SET_ID]
     refs = conf.references
     source = _resolve_source(args, refs)
-    mesh = None
-    if conf.mesh_shape:
-        from spark_examples_tpu.parallel.mesh import make_mesh
-
-        mesh = make_mesh(conf.mesh_shape)
-    driver = VariantsPcaDriver(conf, source, mesh=mesh)
+    # VariantsPcaDriver builds the mesh from --mesh-shape itself.
+    driver = VariantsPcaDriver(conf, source)
     driver.run()
     return 0
 

@@ -157,11 +157,14 @@ class SpanTracer:
             self._events.append(event)
 
     @contextlib.contextmanager
-    def span(self, name: str, **args: Any) -> Iterator[None]:
+    def span(self, name: str, **args: Any) -> Iterator[Dict[str, Any]]:
         """Record one complete ("X") event around the body.
 
         Safe from any thread; nesting is tracked per thread. ``args``
         must be JSON-serializable (they land in the event's ``args``).
+        The body receives the args dict: what it adds there (a result
+        known only at the end, such as an iteration count) lands in the
+        event too.
         """
         tid = threading.get_ident()
         trace_id = current_trace_id()
@@ -171,7 +174,7 @@ class SpanTracer:
         if annotation is not None:
             annotation.__enter__()
         try:
-            yield
+            yield args
         finally:
             if annotation is not None:
                 annotation.__exit__(None, None, None)
@@ -351,19 +354,21 @@ def collection_active() -> bool:
 
 
 @contextlib.contextmanager
-def span(name: str, **args: Any) -> Iterator[None]:
+def span(name: str, **args: Any) -> Iterator[Dict[str, Any]]:
     """Ambient span: records into the session tracer, no-op otherwise.
 
-    The flight recorder (when installed) sees the begin/end transitions
-    regardless of whether a session is active — that is its whole point:
-    a last-seconds record even with full tracing off."""
+    The body receives a dict of further attributes to record at the
+    end (see :meth:`SpanTracer.span`). The flight recorder (when installed)
+    sees the begin/end transitions regardless of whether a session is
+    active — that is its whole point: a last-seconds record even with
+    full tracing off."""
     flightrec.note("span_begin", name, args or None)
     try:
         if not _active:
-            yield
+            yield args
         else:
-            with get_tracer().span(name, **args):
-                yield
+            with get_tracer().span(name, **args) as attrs:
+                yield attrs
     finally:
         flightrec.note("span_end", name, None)
 

@@ -40,7 +40,10 @@ window on a TPU that the stream would scatter on the scan or the
 compiled kernel takes ``"mxu"`` instead when its carrier bucket is at most
 :data:`MXU_MAX_CARRIER_BUCKET`: the window's (N, V_pad) int8
 carrier-count panel is built on the device and contracted on the int8
-MXU. The kill switch keeps the scan for every window.
+MXU. A host-local mesh tile's window takes ``"mxu-tile"`` when no
+carrier count passes int8: each tile builds the panels of its row and
+column ranges from the window's carrier list and adds their product.
+The kill switch keeps the scan for every window.
 """
 
 from __future__ import annotations
@@ -161,17 +164,24 @@ def window_scatter_path(
     tile_shape: Tuple[int, int],
     k: int,
     single_device: bool = False,
+    tile_counts_fit_int8: Optional[bool] = None,
 ) -> str:
     """The path ONE window's scatter takes, counted — host side, per window.
 
     The stream's ``path`` (:func:`resolve_scatter_path`) cannot see the
     carrier bucket ``k``, which varies per window. The single-device
-    accumulator passes ``single_device`` (the mesh tile programs have no
-    MXU update): on a TPU, a window its stream would scatter on the scan
-    or the compiled kernel takes ``"mxu"`` when ``k`` is at most
-    :data:`MXU_MAX_CARRIER_BUCKET`, unless the kill switch is set (on a
-    TPU v5e the MXU update is 5–200× faster than the kernel at N =
-    1,024–6,144, and 185× faster than the scan at N = 3,202, k = 64).
+    accumulator passes ``single_device``: on a TPU, a window its stream
+    would scatter on the scan or the compiled kernel takes ``"mxu"``
+    when ``k`` is at most :data:`MXU_MAX_CARRIER_BUCKET`, unless the
+    kill switch is set (on a TPU v5e the MXU update is 5–200× faster
+    than the kernel at N = 1,024–6,144, and 185× faster than the scan at
+    N = 3,202, k = 64). The host-local mesh accumulator passes
+    ``tile_counts_fit_int8``, the host's check that no carrier repeats
+    past int8 within a site
+    (:func:`spark_examples_tpu.ops.sparse.carrier_counts_fit_int8`): on
+    a TPU such a window takes ``"mxu-tile"``, each tile's row and
+    column carrier-count panels built from the window's carrier list
+    and contracted on the int8 MXU, whatever ``k``.
     Otherwise a kernel path whose (K, C)
     index blocks push a grid step over the VMEM budget takes the chunked
     scan instead (bit-identical), and that fall is counted as
@@ -179,14 +189,15 @@ def window_scatter_path(
     never decided silently inside a trace.
     """
     taken = label = path
-    if (
-        single_device
-        and path in ("scan", "pallas")
-        and k <= MXU_MAX_CARRIER_BUCKET
+    mxu_ok = (
+        path in ("scan", "pallas")
         and _scatter_mode() not in _KILL_SWITCH
         and jax.default_backend() == "tpu"
-    ):
+    )
+    if mxu_ok and single_device and k <= MXU_MAX_CARRIER_BUCKET:
         taken = label = "mxu"
+    elif mxu_ok and tile_counts_fit_int8:
+        taken = label = "mxu-tile"
     elif path != "scan" and kernel_block_rows(*tile_shape, k) is None:
         taken, label = "scan", "fallback-scan"
     from spark_examples_tpu import obs
@@ -194,8 +205,9 @@ def window_scatter_path(
     obs.get_registry().counter(
         "sparse_scatter_path_windows_total",
         "Scatter-route windows by the implementation they took (mxu = "
-        "device-built carrier-count panel on the int8 MXU; fallback-scan "
-        "= a kernel stream's window over the VMEM budget)",
+        "device-built carrier-count panel on the int8 MXU; mxu-tile = a "
+        "mesh tile's row and column panels on the int8 MXU; "
+        "fallback-scan = a kernel stream's window over the VMEM budget)",
     ).labels(path=label).inc()
     return taken
 

@@ -3,7 +3,7 @@
 Every other PCA engine — fused, streamed dense, host-local sparse,
 pod-sparse — materializes N×N tiles of G = XXᵀ, which at N = 10⁶ is
 4 TB of f32: the footprint bound (:meth:`VariantsPcaDriver.
-_sparse_host_g_bytes`) refuses long before the biobank north star.
+_sparse_device_g_bytes`) refuses long before the biobank north star.
 The randomized-subspace literature (arxiv 1808.03374's genotype PCA,
 Halko-Martinsson-Tropp) recovers the top-k eigenpairs of the centered
 Gramian C = H·G·H (H the centering projector) from streamed products

@@ -62,8 +62,11 @@ __all__ = [
     "DEFAULT_SPARSE_DENSITY_THRESHOLD",
     "SCATTER_CHUNK_VARIANTS",
     "carrier_count_panel",
+    "carrier_counts_fit_int8",
+    "carrier_pairs",
     "dense_panel_width",
     "padded_carrier_matrix",
+    "pair_count_panel",
     "scatter_pairs_chunked",
     "sparse_gramian_accumulate",
     "sparse_gramian_blockwise",
@@ -268,6 +271,80 @@ def carrier_count_panel(idx, n_rows: int):
     for carriers in idx.T:
         x += (rows == carriers).astype(jnp.int8)
     return x
+
+
+def carrier_pairs(
+    window_idx: np.ndarray, lens: np.ndarray, sentinel: int
+) -> np.ndarray:
+    """One CSR window → a ``(2, nnz_bucket)`` int32 list of its carriers.
+
+    Row 0 holds each carrier's sample index, row 1 the column of its
+    site within the window, in CSR order; the tail past the window's
+    nnz carries ``sentinel`` samples (dropped by the panel build). The
+    length is nnz rounded up to a power of two, so what crosses to the
+    device, and the panel build it feeds (:func:`pair_count_panel`),
+    scale with the window's carriers — not with its sites times its
+    largest carrier count, as :func:`padded_carrier_matrix` does.
+    """
+    lens = np.asarray(lens, dtype=np.int64)
+    window_idx = np.asarray(window_idx)
+    nnz = int(window_idx.size)
+    pairs = np.full((2, _carrier_bucket(nnz)), sentinel, dtype=np.int32)
+    pairs[0, :nnz] = window_idx
+    pairs[1, :nnz] = np.repeat(
+        np.arange(lens.size, dtype=np.int32), lens
+    )
+    return pairs
+
+
+def carrier_counts_fit_int8(
+    window_idx: np.ndarray, lens: np.ndarray, n_samples: int
+) -> bool:
+    """Does every (sample, site) carrier count of the window fit int8?
+
+    A count is a carrier's repeats within one site, so only a site with
+    more than :data:`~spark_examples_tpu.ops.scatter_kernel.
+    MXU_MAX_CARRIER_BUCKET` carriers can pass it, and only if some
+    carrier repeats more than that many times there. Those sites'
+    entries are sorted by (site, sample) and the longest run of one
+    key is the largest count; every other site is within int8 by its
+    length alone.
+    """
+    from spark_examples_tpu.ops.scatter_kernel import (
+        MXU_MAX_CARRIER_BUCKET,
+    )
+
+    lens = np.asarray(lens, dtype=np.int64)
+    big = lens > MXU_MAX_CARRIER_BUCKET
+    if not big.any():
+        return True
+    keys = np.repeat(np.flatnonzero(big).astype(np.int64), lens[big])
+    keys *= n_samples
+    keys += np.asarray(window_idx)[np.repeat(big, lens)]
+    keys.sort()
+    edges = np.flatnonzero(np.diff(keys)) + 1
+    if edges.size + 1 == keys.size:
+        return True  # no carrier repeats within a site
+    runs = np.diff(np.concatenate([[0], edges, [keys.size]]))
+    return int(runs.max()) <= MXU_MAX_CARRIER_BUCKET
+
+
+def pair_count_panel(samples, sites, n_rows: int, n_cols: int):
+    """``X[n, v] = |{e : samples[e] = n, sites[e] = v}|`` as an
+    ``(n_rows, n_cols)`` int8 panel — built on the device by one
+    scatter-add of the carrier list (:func:`carrier_pairs`), O(nnz).
+
+    Samples outside ``[0, n_rows)`` (the pad sentinel, carriers of
+    another tile's range mapped past the end by the caller) drop out; a
+    repeated carrier counts more than once, as the pair scatter does.
+    The caller has checked that no count passes int8
+    (:func:`carrier_counts_fit_int8`).
+    """
+    return (
+        jnp.zeros((n_rows, n_cols), jnp.int8)
+        .at[samples, sites]
+        .add(jnp.int8(1), mode="drop")
+    )
 
 
 @partial(

@@ -60,6 +60,8 @@ __all__ = [
     "sharded_sketch_panel",
     "sketch_tsqr",
     "sparse_sharded_gramian_blockwise",
+    "EigNotConvergedError",
+    "topk_eig_adaptive",
     "topk_eig_randomized",
 ]
 
@@ -620,8 +622,9 @@ def _sparse_tile_kernels(
     mirror: bool = False,
 ):
     """Compiled kernel set (tile scatter, GSPMD dense fallback, pod
-    dense tile step, symmetric-mirror finalizer) for one (mesh,
-    padded-N, dtype, scatter-path, mirror) geometry — cached on the
+    dense tile step, symmetric-mirror finalizer, int8 MXU tile update)
+    for one (mesh, padded-N, dtype, scatter-path, mirror) geometry —
+    cached on the
     hashable geometry key. ``jax.jit`` caches by function identity, so
     building these as fresh closures per accumulation call would
     re-trace and re-compile the shard_map program on EVERY call (the
@@ -651,7 +654,10 @@ def _sparse_tile_kernels(
     """
     from spark_examples_tpu.ops.gramian import mxu_cross_product_pair
     from spark_examples_tpu.ops.scatter_kernel import scatter_pairs_kernel
-    from spark_examples_tpu.ops.sparse import scatter_pairs_chunked
+    from spark_examples_tpu.ops.sparse import (
+        pair_count_panel,
+        scatter_pairs_chunked,
+    )
 
     compute_dtype = jnp.dtype(compute_name)
     g_sharding = NamedSharding(mesh, P(d_axis, m_axis))
@@ -674,21 +680,21 @@ def _sparse_tile_kernels(
 
     half = tile_rows // 2  # mirror slab split (tiles square there)
 
-    def _tile_scatter(g_tile, idx):
+    def _tile_range(idx, start, size):
+        # Global sample indices → this tile's frame; outside it, the
+        # out-of-bounds sentinel ``size`` (dropped downstream).
+        return jnp.where((idx >= start) & (idx < start + size),
+                         idx - start, size)
+
+    def mesh_tile_scatter(g_tile, idx):
         # Re-base global carrier indices into this device's tile frame;
         # anything outside the tile becomes an out-of-bounds sentinel
         # and the drop-mode scatter ignores it. Tiles partition the
         # (i, j) pair space, so the union over devices is exactly one
         # +1 per co-occurring pair — the dense path's count.
         d_idx, m_idx = _grid_pos()
-        r0 = d_idx * tile_rows
-        c0 = m_idx * tile_cols
-        li = jnp.where(
-            (idx >= r0) & (idx < r0 + tile_rows), idx - r0, tile_rows
-        )
-        lj = jnp.where(
-            (idx >= c0) & (idx < c0 + tile_cols), idx - c0, tile_cols
-        )
+        li = _tile_range(idx, d_idx * tile_rows, tile_rows)
+        lj = _tile_range(idx, m_idx * tile_cols, tile_cols)
         if mirror:
             # Off-diagonal slab partition: the upper partner owns its
             # top row-slab, the lower its right column-slab; the rest
@@ -707,7 +713,7 @@ def _sparse_tile_kernels(
 
     scatter = jax.jit(
         jax.shard_map(
-            _tile_scatter,
+            mesh_tile_scatter,
             mesh=mesh,
             in_specs=(P(d_axis, m_axis), P(None, None)),
             out_specs=P(d_axis, m_axis),
@@ -716,9 +722,42 @@ def _sparse_tile_kernels(
     )
 
     @partial(jax.jit, donate_argnums=(0,), out_shardings=g_sharding)
-    def _accum_dense(g, xp):
+    def mesh_tile_dense(g, xp):
         xb = unpack_indicator_block(xp, 8 * xp.shape[1])
         return g + mxu_cross_product(xb, g.dtype, compute_dtype)
+
+    @partial(jax.jit, donate_argnums=(0,), static_argnames=("n_cols",))
+    def mesh_tile_mxu(g, pairs, n_cols):
+        # The int8 MXU tile update: each device builds the carrier-count
+        # panels of its own row range and its own column range from the
+        # window's replicated carrier list (carriers outside a range drop
+        # out) and adds P_r · P_cᵀ to its tile — the single-device MXU
+        # update cut to a tile. Tiles partition the pair space, so the
+        # union over devices is the window's X·Xᵀ, exactly.
+        def body(g_tile, pairs):
+            d_idx, m_idx = _grid_pos()
+            samples, sites = pairs[0], pairs[1]
+            rows = pair_count_panel(
+                _tile_range(samples, d_idx * tile_rows, tile_rows),
+                sites, tile_rows, n_cols,
+            )
+            cols = pair_count_panel(
+                _tile_range(samples, m_idx * tile_cols, tile_cols),
+                sites, tile_cols, n_cols,
+            )
+            # Built apart from the contraction (fused, the panel build
+            # would be recomputed per output tile of the matmul).
+            rows, cols = jax.lax.optimization_barrier((rows, cols))
+            return g_tile + mxu_cross_product_pair(
+                rows, cols, g_tile.dtype, compute_dtype
+            )
+
+        return jax.shard_map(
+            body,
+            mesh=mesh,
+            in_specs=(P(d_axis, m_axis), P(None, None)),
+            out_specs=P(d_axis, m_axis),
+        )(g, pairs)
 
     all_axes = tuple(mesh.axis_names)
 
@@ -842,7 +881,17 @@ def _sparse_tile_kernels(
             donate_argnums=(0,),
         )
 
-    return scatter, _accum_dense, accum_dense_pod, mirror_fill
+    return scatter, mesh_tile_dense, accum_dense_pod, mirror_fill, mesh_tile_mxu
+
+
+@functools.lru_cache(maxsize=16)
+def _sharded_zeros(shape, dtype_name: str, sharding):
+    """A zero array made in place on each device of ``sharding`` — never
+    whole on one device first (at N = 49,960 a whole f32 G is 10 GB)."""
+    return jax.jit(
+        lambda: jnp.zeros(shape, jnp.dtype(dtype_name)),
+        out_shardings=sharding,
+    )
 
 
 @partial(jax.jit, static_argnames=("n",))
@@ -1406,8 +1455,11 @@ def sparse_sharded_gramian_blockwise(
     )
     from spark_examples_tpu.ops.sparse import (
         DEFAULT_SPARSE_DENSITY_THRESHOLD,
+        _carrier_bucket,
         _note_window,
         _pad_rows_for_scan,
+        carrier_counts_fit_int8,
+        carrier_pairs,
         dense_panel_width,
         padded_carrier_matrix,
         window_route,
@@ -1456,9 +1508,13 @@ def sparse_sharded_gramian_blockwise(
         np.dtype(accum_dtype).name,
         np.dtype(compute_dtype).name,
     )
-    _, _accum_dense, _accum_dense_pod, _mirror_fill = _sparse_tile_kernels(
-        *kernel_key, scatter_path, mirror
-    )
+    (
+        _,
+        _accum_dense,
+        _accum_dense_pod,
+        _mirror_fill,
+        _tile_mxu,
+    ) = _sparse_tile_kernels(*kernel_key, scatter_path, mirror)
 
     def scatter(g, idx):
         # The carrier bucket K is known only per window: the stream's
@@ -1469,10 +1525,38 @@ def sparse_sharded_gramian_blockwise(
         )
         return _sparse_tile_kernels(*kernel_key, path, mirror)[0](g, idx)
 
+    def scatter_window(g, window_idx, lens):
+        # Host-local tiles: the int8 MXU tile update where the host has
+        # checked every carrier count fits int8, else the carrier matrix
+        # through the stream's scatter program.
+        path = window_scatter_path(
+            scatter_path,
+            (tile_rows, tile_cols),
+            _carrier_bucket(int(lens.max()) if lens.size else 0),
+            tile_counts_fit_int8=carrier_counts_fit_int8(
+                window_idx, lens, n_samples
+            ),
+        )
+        if path == "mxu-tile":
+            pairs = carrier_pairs(window_idx, lens, sentinel=n_padded)
+            return _tile_mxu(
+                g,
+                jax.device_put(pairs, idx_sharding),
+                n_cols=dense_panel_width(int(lens.size), width),
+            )
+        idx = padded_carrier_matrix(
+            window_idx,
+            lens,
+            sentinel=n_padded,
+            n_rows=_pad_rows_for_scan(lens.size),
+        )
+        kernels = _sparse_tile_kernels(*kernel_key, path, mirror)
+        return kernels[0](g, jax.device_put(idx, idx_sharding))
+
     idx_sharding = NamedSharding(mesh, P(None, None))
-    g = jax.device_put(
-        jnp.zeros((n_padded, n_padded), dtype=accum_dtype), g_sharding
-    )
+    g = _sharded_zeros(
+        (n_padded, n_padded), np.dtype(accum_dtype).name, g_sharding
+    )()
     with obs.span("gramian.sparse.accumulate", n=n_samples, sharded=True):
         if spans:
             # Pod mode: every step is a collective device program, so
@@ -1530,7 +1614,15 @@ def sparse_sharded_gramian_blockwise(
             lo, hi = addressable_sample_bounds(
                 mesh, g_sharding, n_padded
             )
-            for window_idx, lens in windows:
+            windows = iter(windows)
+            while True:
+                # The dispatching thread's wait for its next window, as
+                # on the single-device stream; the last finds the end.
+                with obs.span("feed.wait"):
+                    window = next(windows, None)
+                if window is None:
+                    break
+                window_idx, lens = window
                 lens = np.asarray(lens)
                 _check_indices(np.asarray(window_idx), n_samples)
                 window_idx, lens = restrict_window_to_sample_range(
@@ -1545,13 +1637,7 @@ def sparse_sharded_gramian_blockwise(
                     variants=int(lens.size),
                 ):
                     if route == "scatter":
-                        idx = padded_carrier_matrix(
-                            window_idx,
-                            lens,
-                            sentinel=n_padded,
-                            n_rows=_pad_rows_for_scan(lens.size),
-                        )
-                        g = scatter(g, jax.device_put(idx, idx_sharding))
+                        g = scatter_window(g, window_idx, lens)
                     else:
                         dense_width = dense_panel_width(
                             int(lens.size), width
@@ -1571,6 +1657,140 @@ def sparse_sharded_gramian_blockwise(
     if n_padded == n_samples:
         return g
     return _trim_square(g, n_samples)
+
+
+# The mesh finish's programs, at module level so that a second analysis
+# traces and compiles nothing: each is cached on its static arguments.
+# Every product runs at float32 matmul precision (a TPU's default runs
+# f32 matmuls in bfloat16 passes, which stalls the iteration near 1e-4).
+
+
+@jax.jit
+def mesh_center(g):
+    """Double-centre a (possibly mesh-sharded) Gramian in float32."""
+    return double_center(g)
+
+
+def _sweep(c, q, length):
+    def body(q, _):
+        y = c @ q  # the only O(N²) op — sharded with C
+        q, _ = jnp.linalg.qr(y)
+        return q, None
+
+    q, _ = jax.lax.scan(body, q, None, length=length)
+    return q
+
+
+def _ritz_from(q, b):
+    # Rayleigh–Ritz on the current subspace; (p, p) stays small.
+    w, u = jnp.linalg.eigh(b)
+    order = jnp.argsort(-jnp.abs(w))
+    return q @ u[:, order], w[order]
+
+
+@partial(jax.jit, static_argnames=("iters",))
+def mesh_subspace_eig(c, q, iters):
+    """``iters`` sweeps of subspace iteration, then Rayleigh–Ritz."""
+    with jax.default_matmul_precision("float32"):
+        q = _sweep(c, q, iters)
+        return _ritz_from(q, q.T @ (c @ q))
+
+
+@partial(jax.jit, static_argnames=("k", "max_iters", "chunk"))
+def mesh_subspace_eig_adaptive(c, q, tol, k, max_iters, chunk):
+    """Sweeps in chunks of ``chunk`` until every top-k Ritz pair's
+    relative residual is below ``tol`` or ``max_iters`` are spent →
+    ``(vecs, vals, iterations used, largest top-k residual)``."""
+    with jax.default_matmul_precision("float32"):
+        tiny = jnp.finfo(c.dtype).tiny
+
+        def cond(state):
+            _, _, it, rel = state
+            return jnp.logical_and(~(rel < tol), it < max_iters)
+
+        def body(state):
+            q, _, it, _ = state
+            q = _sweep(c, q, chunk)
+            y = c @ q  # reused: residual check AND the final Ritz matrix
+            b = q.T @ y
+            w, u = jnp.linalg.eigh(b)
+            order = jnp.argsort(-jnp.abs(w))
+            uk, wk = u[:, order[:k]], w[order[:k]]
+            # Standard eigenpair residual per top-k Ritz pair:
+            # ‖C v − λ v‖ with v = q·u, C v = y·u — no extra O(N²) work.
+            rk = y @ uk - (q @ uk) * wk
+            rel = jnp.max(
+                jnp.linalg.norm(rk, axis=0)
+                / jnp.maximum(jnp.abs(wk), tiny)
+            )
+            return q, b, it + chunk, rel
+
+        q, b, used, rel = jax.lax.while_loop(
+            cond,
+            body,
+            (
+                q,
+                jnp.zeros((q.shape[1], q.shape[1]), c.dtype),
+                jnp.int32(0),
+                jnp.asarray(jnp.inf, c.dtype),
+            ),
+        )
+        # b is the last chunk's q.T @ (c @ q): Rayleigh–Ritz without
+        # recomputing the O(N²·p) product.
+        vecs, vals = _ritz_from(q, b)
+        return vecs, vals, used, rel
+
+
+class EigNotConvergedError(RuntimeError):
+    """The mesh finish's subspace iteration spent its iterations without
+    bringing every top-k Ritz residual under the tolerance."""
+
+
+# The mesh finish's defaults: the relative Ritz residual it stops on
+# (float32 iteration reaches 1e-7 to 2e-6 on population-structure
+# cohorts, so 1e-5 leaves room above that floor while bounding the
+# eigenvector error at O(1e-5 / relative gap)); the sweeps between two
+# residual checks (population structure converges in a few sweeps, but
+# the coordinates keep improving for a few more after the residual first
+# passes the tolerance, and a sweep costs one read of C); and the
+# iteration cap past which it raises.
+MESH_EIG_TOL = 1e-5
+MESH_EIG_CHECK_EVERY = 10
+MESH_EIG_MAX_ITERS = 100
+
+
+def _eig_panel(c, k, oversample, seed, mesh):
+    """The random starting panel, global where the mesh spans processes."""
+    n = c.shape[0]
+    # The k+1-values convention lives in ONE helper (ops/pcoa.py): the
+    # panel must carry a Ritz value past index k-1 or the spectral-gap
+    # check silently never fires and a flat-spectrum cohort's ambiguity
+    # goes unreported.
+    p = randomized_panel_width(n, k, oversample)
+    q0 = jax.random.normal(jax.random.PRNGKey(seed), (n, p), dtype=c.dtype)
+    if mesh is not None and jax.process_count() > 1:
+        # Multi-controller: the panel must be a global (replicated) array
+        # to enter a jit alongside the process-spanning C — every process
+        # derives the identical panel from the same key.
+        host_q0 = np.asarray(q0)
+        q0 = jax.make_array_from_callback(
+            host_q0.shape,
+            NamedSharding(mesh, P(None, None)),
+            lambda idx: host_q0[idx],
+        )
+    return q0
+
+
+def _replicate_result(vecs, vals, mesh):
+    if mesh is not None and jax.process_count() > 1:
+        # The (N, k+p) panel result is small even at stress N; replicate it
+        # so hosts can read coordinates without touching the sharded C.
+        rep = NamedSharding(mesh, P(None, None))
+        vecs = jax.jit(lambda a: a, out_shardings=rep)(vecs)
+        vals = jax.jit(
+            lambda a: a, out_shardings=NamedSharding(mesh, P(None))
+        )(vals)
+    return vecs, vals
 
 
 def topk_eig_randomized(
@@ -1593,7 +1813,8 @@ def topk_eig_randomized(
     the small replicated panel. Subspace iteration on C converges to the
     invariant subspace of the largest-|λ| eigenvalues (signs recovered via
     Rayleigh quotients), which is exactly the MLlib |λ|-ordering
-    (see :mod:`spark_examples_tpu.ops.pcoa`).
+    (see :mod:`spark_examples_tpu.ops.pcoa`). Every product runs at
+    float32 matmul precision.
 
     Returns ``(vecs (N,k), vals (k,))`` ordered by |λ| descending, signs
     normalized.
@@ -1613,123 +1834,68 @@ def topk_eig_randomized(
     ``timer`` is passed. The Ritz values needed for the check come free
     from the oversampled panel.
 
-    ``tol`` (opt-in) makes the iteration count adaptive: the power sweep
-    runs in chunks of ``check_every`` under ``lax.while_loop``, stopping
-    once every top-k Ritz pair's relative residual ``‖C·v − λ·v‖/|λ|``
-    drops below ``tol``, or at the hard cap ``iters`` (rounded up to a
-    whole chunk). The residual is the standard eigenpair criterion — it
-    bounds eigenvector error at O(tol / gap), which is honest where
-    Ritz-value stagnation is not (values converge at the square of the
-    vector rate). The check reuses the chunk's own ``C @ q`` product, so
-    its marginal cost is one power-iteration-equivalent per chunk, and
-    the final Rayleigh–Ritz reuses the last chunk's small matrix rather
-    than recomputing the O(N²·p) product. The chunked sweep applies the
-    same operations in the same order as the fixed path, so an
-    unconverged adaptive run (``tol=0``, ``iters`` a chunk multiple)
-    yields the fixed path's subspace; on sharp population-structure
-    spectra convergence lands well under the cap — pure chip time saved
-    at stress N. The iteration count used lands in the stage-timer
-    report.
+    ``tol`` (opt-in) makes the iteration count adaptive: see
+    :func:`topk_eig_adaptive`, which this returns the pairs of (an
+    unconverged adaptive run returns its last subspace here; the mesh
+    finish raises instead).
     """
-    n = c.shape[0]
-    # The k+1-values convention lives in ONE helper (ops/pcoa.py): the
-    # panel must carry a Ritz value past index k-1 or the spectral-gap
-    # check silently never fires and a flat-spectrum cohort's ambiguity
-    # goes unreported.
-    p = randomized_panel_width(n, k, oversample)
-    q0 = jax.random.normal(jax.random.PRNGKey(seed), (n, p), dtype=c.dtype)
-    if mesh is not None and jax.process_count() > 1:
-        # Multi-controller: the panel must be a global (replicated) array
-        # to enter a jit alongside the process-spanning C — every process
-        # derives the identical panel from the same key.
-        host_q0 = np.asarray(q0)
-        q0 = jax.make_array_from_callback(
-            host_q0.shape,
-            NamedSharding(mesh, P(None, None)),
-            lambda idx: host_q0[idx],
-        )
-
-    def _ritz(c, q):
-        # Rayleigh–Ritz on the current subspace; (p, p) stays small.
-        b = q.T @ (c @ q)
-        w, u = jnp.linalg.eigh(b)
-        order = jnp.argsort(-jnp.abs(w))
-        return q @ u[:, order], w[order]
-
-    def _sweep(c, q, length):
-        def body(q, _):
-            y = c @ q  # the only O(N²) op — sharded with C
-            q, _ = jnp.linalg.qr(y)
-            return q, None
-
-        q, _ = jax.lax.scan(body, q, None, length=length)
-        return q
-
-    @partial(jax.jit, static_argnames=("iters",))
-    def _run(c, q, iters):
-        return _ritz(c, _sweep(c, q, iters))
-
-    @partial(jax.jit, static_argnames=("max_iters", "chunk"))
-    def _run_adaptive(c, q, max_iters, chunk):
-        tiny = jnp.finfo(c.dtype).tiny
-
-        def cond(state):
-            _, _, it, converged = state
-            return jnp.logical_and(~converged, it < max_iters)
-
-        def body(state):
-            q, _, it, _ = state
-            q = _sweep(c, q, chunk)
-            y = c @ q  # reused: residual check AND the final Ritz matrix
-            b = q.T @ y
-            w, u = jnp.linalg.eigh(b)
-            order = jnp.argsort(-jnp.abs(w))
-            uk, wk = u[:, order[:k]], w[order[:k]]
-            # Standard eigenpair residual per top-k Ritz pair:
-            # ‖C v − λ v‖ with v = q·u, C v = y·u — no extra O(N²) work.
-            rk = y @ uk - (q @ uk) * wk
-            rel = jnp.max(
-                jnp.linalg.norm(rk, axis=0)
-                / jnp.maximum(jnp.abs(wk), tiny)
-            )
-            return q, b, it + chunk, rel < tol
-
-        q, b, used, _ = jax.lax.while_loop(
-            cond,
-            body,
-            (
-                q,
-                jnp.zeros((q.shape[1], q.shape[1]), c.dtype),
-                jnp.int32(0),
-                jnp.asarray(False),
-            ),
-        )
-        # b is the last chunk's q.T @ (c @ q): Rayleigh–Ritz without
-        # recomputing the O(N²·p) product.
-        w, u = jnp.linalg.eigh(b)
-        order = jnp.argsort(-jnp.abs(w))
-        return q @ u[:, order], w[order], used
-
     if tol is not None:
-        chunk = max(1, min(check_every, iters))
-        vecs, vals, used = _run_adaptive(c, q0, iters, chunk)
-        if timer is not None:
-            timer.note(
-                f"randomized eig: {int(used)}/{iters} iterations "
-                f"(tol={tol:g})"
-            )
-    else:
-        vecs, vals = _run(c, q0, iters)
-    if mesh is not None and jax.process_count() > 1:
-        # The (N, k+p) panel result is small even at stress N; replicate it
-        # so hosts can read coordinates without touching the sharded C.
-        rep = NamedSharding(mesh, P(None, None))
-        vecs = jax.jit(lambda a: a, out_shardings=rep)(vecs)
-        vals = jax.jit(
-            lambda a: a, out_shardings=NamedSharding(mesh, P(None))
-        )(vals)
+        vecs, vals, _, _ = topk_eig_adaptive(
+            c, k, tol, oversample=oversample, iters=iters, seed=seed,
+            mesh=mesh, timer=timer, gap_warn_ratio=gap_warn_ratio,
+            check_every=check_every,
+        )
+        return vecs, vals
+    q0 = _eig_panel(c, k, oversample, seed, mesh)
+    vecs, vals = _replicate_result(*mesh_subspace_eig(c, q0, iters), mesh)
     check_spectral_gap(np.asarray(vals), k, gap_warn_ratio, timer)
     return normalize_eigvec_signs(vecs[:, :k]), vals[:k]
+
+
+def topk_eig_adaptive(
+    c,
+    k: int,
+    tol: float,
+    oversample: int = DEFAULT_RANDOMIZED_OVERSAMPLE,
+    iters: int = 30,
+    seed: int = 0,
+    mesh: Mesh = None,
+    timer=None,
+    gap_warn_ratio: float = 0.95,
+    check_every: int = 5,
+):
+    """:func:`topk_eig_randomized` with the iteration count adaptive →
+    ``(vecs, vals, iterations used, largest top-k residual)``.
+
+    The power sweep runs in chunks of ``check_every`` under
+    ``lax.while_loop``, stopping once every top-k Ritz pair's relative
+    residual ``‖C·v − λ·v‖/|λ|`` drops below ``tol``, or at the hard cap
+    ``iters`` (rounded up to a whole chunk). The residual is the
+    standard eigenpair criterion — it bounds eigenvector error at
+    O(tol / gap), which is honest where Ritz-value stagnation is not
+    (values converge at the square of the vector rate). The check reuses
+    the chunk's own ``C @ q`` product, so its marginal cost is one
+    power-iteration-equivalent per chunk, and the final Rayleigh–Ritz
+    reuses the last chunk's small matrix rather than recomputing the
+    O(N²·p) product. The chunked sweep applies the same operations in
+    the same order as the fixed path, so an unconverged adaptive run
+    (``tol=0``, ``iters`` a chunk multiple) yields the fixed path's
+    subspace. The iteration count used lands in the stage-timer report.
+    """
+    q0 = _eig_panel(c, k, oversample, seed, mesh)
+    chunk = max(1, min(check_every, iters))
+    vecs, vals, used, rel = mesh_subspace_eig_adaptive(
+        c, q0, tol, k=k, max_iters=iters, chunk=chunk
+    )
+    used, rel = int(used), float(rel)
+    if timer is not None:
+        timer.note(
+            f"randomized eig: {used}/{iters} iterations "
+            f"(tol={tol:g}, residual {rel:.2e})"
+        )
+    vecs, vals = _replicate_result(vecs, vals, mesh)
+    check_spectral_gap(np.asarray(vals), k, gap_warn_ratio, timer)
+    return normalize_eigvec_signs(vecs[:, :k]), vals[:k], used, rel
 
 
 def sharded_pcoa(
@@ -1745,10 +1911,17 @@ def sharded_pcoa(
     Small N: gather the centered matrix and run dense ``eigh`` (exact, the
     replicated-eigh fallback of SURVEY.md §7). Large N: keep C sharded and
     use randomized subspace iteration — at the stress scale C is never
-    materialized on any single device or host.
+    materialized on any single device or host. The iteration stops on
+    the relative Ritz residual ``eig_tol`` (default
+    :data:`MESH_EIG_TOL`) and raises :class:`EigNotConvergedError` after
+    :data:`MESH_EIG_MAX_ITERS` without it, rather than return an
+    unconverged answer.
     """
-    c = jax.jit(double_center)(g)
-    n = c.shape[0]
+    from spark_examples_tpu import obs
+
+    n = g.shape[0]
+    with obs.span("finish.mesh.center", n=n):
+        c = mesh_center(g)
     if n <= dense_eigh_limit:
         if not c.is_fully_addressable:
             # Process-spanning shards: replicate through a collective jit
@@ -1769,7 +1942,21 @@ def sharded_pcoa(
         return topk_with_gap_check(
             lambda kk: principal_components(c, kk), k, n, timer=timer
         )
-    return topk_eig_randomized(c, k, mesh=mesh, timer=timer, tol=eig_tol)
+    tol = MESH_EIG_TOL if eig_tol is None else eig_tol
+    with obs.span("finish.mesh.eig", n=n, k=k, tol=tol) as attrs:
+        vecs, vals, used, rel = topk_eig_adaptive(
+            c, k, tol, iters=MESH_EIG_MAX_ITERS, mesh=mesh, timer=timer,
+            check_every=MESH_EIG_CHECK_EVERY,
+        )
+        attrs.update(iterations=used, residual=rel)
+    if not rel < tol:
+        raise EigNotConvergedError(
+            f"mesh finish: the top-{k} Ritz residual is {rel:.3g} after "
+            f"{used} iterations, over the tolerance {tol:g}; the "
+            "coordinates have not converged (a near-degenerate spectrum "
+            "needs a looser --eig-tol, or --precise)"
+        )
+    return vecs, vals
 
 
 # -- Gramian-free sketch panels (ops/sketch.py's mesh half) ------------------

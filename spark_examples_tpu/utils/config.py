@@ -189,11 +189,11 @@ class PcaConfig(GenomicsConfig):
     # N above which the PCoA eigendecomposition switches from dense eigh
     # to randomized subspace iteration (the sharded-eig path).
     dense_eigh_limit: int = 8192
-    # Opt-in adaptive convergence for the randomized eig: stop once every
-    # top-k Ritz pair's relative residual ‖C·v − λ·v‖/|λ| drops below
-    # this (None = the fixed 30-iteration sweep); eigenvector error is
-    # then O(tol/gap). Cuts O(N²) matmuls ~2-3× on sharp spectra — pure
-    # chip time at stress N.
+    # Convergence target of the randomized eig: the mesh finish stops
+    # once every top-k Ritz pair's relative residual ‖C·v − λ·v‖/|λ|
+    # drops below this (None = its default, 1e-5) and raises if it never
+    # does; eigenvector error is then O(tol/gap). The fused finish
+    # retries below it (None = 1e-3).
     eig_tol: Optional[float] = None
     # Shard-parallel host ingest workers (fused paths): 0 = auto (core
     # count capped at 16 for shard extraction; min(4, cores) for the
@@ -706,12 +706,12 @@ def add_pca_flags(p: argparse.ArgumentParser) -> None:
         type=float,
         default=None,
         help="Eigensolver convergence target |Cv - lv|/|l| per top-k "
-        "pair; eigenvector error is then O(tol/gap). On the randomized "
-        "(sharded / large-N) path: adaptive early stopping (default: "
-        "fixed 30-iteration sweep), cutting device matmuls ~2-3x on "
-        "sharp spectra. On the fused path (--pca-mode auto/fused): the "
-        "residual check-and-retry bar (default 1e-3). The iteration "
-        "count used appears in the stage report",
+        "pair; eigenvector error is then O(tol/gap). On the mesh "
+        "finish's randomized path (N above --dense-eigh-limit): the "
+        "residual it stops on (default 1e-5), raising if 100 "
+        "iterations do not reach it. On the fused path (--pca-mode "
+        "auto/fused): the residual check-and-retry bar (default 1e-3). "
+        "The iteration count used appears in the stage report",
     )
 
 

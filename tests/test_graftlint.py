@@ -307,6 +307,18 @@ class TestSchemaSharing:
             "PR"
         )
 
+    def test_extracted_finish_spans_match_schema_exactly(self, project):
+        schema = span_contract_mod.load_schema(REPO_ROOT)
+        extracted = {
+            name
+            for name in span_contract_mod.extract_span_names(project)
+            if name.startswith("finish.")
+        }
+        assert extracted == set(schema._FINISH_SPANS), (
+            "emitted finish.* span literals and the validate_trace "
+            "schema diverged — change both sides in one PR"
+        )
+
     def test_extracted_pairhmm_spans_match_schema_exactly(self, project):
         schema = span_contract_mod.load_schema(REPO_ROOT)
         extracted = {

@@ -262,12 +262,18 @@ class TestAdaptiveEig:
         assert used < 60  # converged before the cap
 
     def test_sharded_pcoa_threads_eig_tol(self):
-        """eig_tol flows through sharded_pcoa's randomized branch.
+        """eig_tol flows through sharded_pcoa's randomized branch: a
+        tolerance float32 iteration reaches is met, one below its floor
+        raises rather than return an unconverged answer.
 
         Structured spectrum (population groups): the randomized path is
         rotation-fragile on flat random spectra by design — the same
         reason test_sharded_pcoa_randomized_path compares at 1e-2.
         """
+        from spark_examples_tpu.parallel.sharded import (
+            EigNotConvergedError,
+        )
+
         rng = np.random.default_rng(3)
         groups = rng.integers(0, 3, size=96)
         af = rng.beta(0.4, 1.2, size=(3, 2048))
@@ -282,12 +288,16 @@ class TestAdaptiveEig:
             2,
             mesh,
             dense_eigh_limit=8,  # force the randomized branch
-            eig_tol=1e-7,
+            eig_tol=1e-6,
         )
         err = np.abs(
             np.abs(np.asarray(approx)) - np.abs(np.asarray(exact))
         ).max()
         assert err < 1e-4, err
+        with pytest.raises(EigNotConvergedError, match="1e-09"):
+            sharded_pcoa(
+                jnp.asarray(g), 2, mesh, dense_eigh_limit=8, eig_tol=1e-9
+            )
 
 
 def test_cli_pca_with_mesh_flag(capsys, tmp_path):

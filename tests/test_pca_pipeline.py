@@ -281,7 +281,7 @@ class TestFusedPcaMode:
 
 def test_stream_similarity_host_memory_fence():
     """The stream alternate now runs through the sparse device engine:
-    the bound is the streaming-sparse per-host footprint (the f32 G
+    the bound is the streaming-sparse per-device footprint (the f32 G
     tiles), NOT the historical 16·N² host peak (round-4 verdict) —
     past it the refusal is still loud, never a silent OOM. The full
     bound matrix lives in tests/test_sparse_gramian.py."""
@@ -290,11 +290,11 @@ def test_stream_similarity_host_memory_fence():
     calls = list(driver.get_calls(driver.get_data()))
     with pytest.raises(ValueError, match="GiB"):
         driver.get_similarity_matrix_stream(
-            iter(calls), max_host_bytes=4 * 12 * 12 - 1
+            iter(calls), max_device_bytes=4 * 12 * 12 - 1
         )
-    # At exactly the f32-G per-host footprint it runs — a budget 4x
+    # At exactly the f32-G per-device footprint it runs — a budget 4x
     # under the old int64-G + f32-copy + jax-buffer peak.
     out = driver.get_similarity_matrix_stream(
-        iter(calls), max_host_bytes=4 * 12 * 12
+        iter(calls), max_device_bytes=4 * 12 * 12
     )
     assert out.shape == (12, 12)

@@ -469,7 +469,7 @@ class TestDriverSketchMode:
         # ...and flips to sketch exactly when the exact footprint bound
         # would refuse (the same 4 GiB line).
         monkeypatch.setattr(
-            auto, "_sparse_host_g_bytes", lambda: (4 << 30) + 1
+            auto, "_sparse_device_g_bytes", lambda: (4 << 30) + 1
         )
         assert auto.sketch_selected()
 

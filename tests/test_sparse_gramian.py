@@ -428,7 +428,7 @@ class TestDriverSparseMode:
 
 class TestStreamFootprintBound:
     """Satellite: the 16·N² host refusal (round-4 verdict) is gone;
-    the bound is the streaming-sparse per-host G footprint."""
+    the bound is the streaming-sparse per-device G footprint."""
 
     def _driver(self, mesh_spec=None):
         conf = PcaConfig(
@@ -446,7 +446,7 @@ class TestStreamFootprintBound:
         driver = self._driver()
         calls = list(driver.get_calls(driver.get_data()))
         out = driver.get_similarity_matrix_stream(
-            iter(calls), max_host_bytes=16 * 12 * 12 - 1
+            iter(calls), max_device_bytes=16 * 12 * 12 - 1
         )
         assert out.shape == (12, 12)
 
@@ -454,15 +454,15 @@ class TestStreamFootprintBound:
         driver = self._driver()
         calls = list(driver.get_calls(driver.get_data()))
         with pytest.raises(
-            ValueError, match="per-host f32 Gramian tiles"
+            ValueError, match="per-device f32 Gramian tile"
         ) as exc:
             driver.get_similarity_matrix_stream(
-                iter(calls), max_host_bytes=4 * 12 * 12 - 1
+                iter(calls), max_device_bytes=4 * 12 * 12 - 1
             )
-        assert "max_host_bytes" in str(exc.value)
+        assert "max_device_bytes" in str(exc.value)
         # AT the f32-G footprint it runs.
         out = driver.get_similarity_matrix_stream(
-            iter(calls), max_host_bytes=4 * 12 * 12
+            iter(calls), max_device_bytes=4 * 12 * 12
         )
         assert out.shape == (12, 12)
 
@@ -478,11 +478,11 @@ class TestStreamFootprintBound:
     def test_mesh_footprint_accounts_tiles(self):
         meshed = self._driver("data:2,model:2")
         meshless = self._driver()
-        # Single-controller: every tile is addressable, so the per-host
-        # sum equals the padded f32 G — the accounting is per-HOST, and
-        # only a process-spanning mesh shrinks it.
-        assert meshed._sparse_host_g_bytes() == 4 * 12 * 12
-        assert meshless._sparse_host_g_bytes() == 4 * 12 * 12
+        # The bound is one device's tile: each of the 2x2 grid's
+        # devices holds a 6 x 6 quarter of G; meshless, one device holds
+        # all of it.
+        assert meshed._sparse_device_g_bytes() == 4 * 6 * 6
+        assert meshless._sparse_device_g_bytes() == 4 * 12 * 12
 
 
 class TestPanelWidthConvention:

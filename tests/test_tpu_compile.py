@@ -146,3 +146,67 @@ def test_sharded_scatter_kernel_compiles_on_2x2_mesh(topo, k):
         _spec((8192, k), jnp.int32, NamedSharding(mesh, P(None, None))),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _mesh_2x2(topo):
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("data", "model"))
+
+
+def test_mesh_tile_mxu_update_compiles_at_ukb50k(topo):
+    """The int8 MXU tile update of the host-local mesh stream at the
+    ukb50k_exome_rare.mesh cell's shape: a 49,960^2 f32 G in 24,980^2
+    tiles on the 2x2 mesh, one 8,192-site window's carrier list of
+    262,144 entries. Each chip's program holds its tile, the two int8
+    panels and the contraction's transients within one chip's 16 GB."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_examples_tpu.parallel.sharded import _sparse_tile_kernels
+
+    mesh = _mesh_2x2(topo)
+    update = _sparse_tile_kernels(
+        mesh, "data", "model", 49960, 24980, 24980, "float32", "int8",
+        "scan", False,
+    )[4]
+    compiled = update.lower(
+        _spec((49960, 49960), jnp.float32,
+              NamedSharding(mesh, P("data", "model"))),
+        _spec((2, 262144), jnp.int32, NamedSharding(mesh, P(None, None))),
+        n_cols=8192,
+    ).compile()
+    assert "convolution" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    tile = 24980 * 24980 * 4
+    assert mem.output_size_in_bytes >= tile
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_mesh_finish_compiles_at_ukb50k(topo):
+    """The mesh finish's centring and adaptive subspace eigensolve at
+    N = 49,960 on the 2x2 mesh: per chip one 24,980^2 tile of the centred
+    matrix besides G's, within one chip's 16 GB."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_examples_tpu.ops.pcoa import randomized_panel_width
+    from spark_examples_tpu.parallel.sharded import (
+        MESH_EIG_CHECK_EVERY,
+        MESH_EIG_MAX_ITERS,
+        mesh_center,
+        mesh_subspace_eig_adaptive,
+    )
+
+    mesh = _mesh_2x2(topo)
+    tiled = NamedSharding(mesh, P("data", "model"))
+    center = mesh_center.lower(
+        _spec((49960, 49960), jnp.float32, tiled)).compile()
+    mem = center.memory_analysis()
+    assert mem.temp_size_in_bytes + 2 * 24980 * 24980 * 4 < 16e9
+    p = randomized_panel_width(49960, 2)
+    eig = mesh_subspace_eig_adaptive.lower(
+        _spec((49960, 49960), jnp.float32, tiled),
+        _spec((49960, p), jnp.float32, NamedSharding(mesh, P(None, None))),
+        1e-5, k=2, max_iters=MESH_EIG_MAX_ITERS, chunk=MESH_EIG_CHECK_EVERY,
+    ).compile()
+    mem = eig.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9

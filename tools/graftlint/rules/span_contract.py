@@ -231,6 +231,7 @@ class SpanContractRule:
             ("feed.", "_FEED_SPANS"),
             ("job.", "_JOB_SPANS"),
             ("gramian.sparse.", "_SPARSE_SPANS"),
+            ("finish.", "_FINISH_SPANS"),
             ("gramian.sketch.", "_SKETCH_SPANS"),
             ("pairhmm.", "_PAIRHMM_SPANS"),
         ):
