@@ -312,11 +312,15 @@ def validate_trace(path: str) -> List[str]:
 _WIRE_COUNTERS = ("wire_frames_total", "wire_frame_bytes_total")
 _WIRE_HISTOGRAM = "wire_frame_decode_seconds"
 
-# Parallel-ingest metric contract: the block counter carries a mode
-# label ("native"/"python" — which build path produced the block), and
-# the build-latency histogram exposes the full Prometheus triplet.
-# Checked only when present, like the wire metrics.
-_INGEST_COUNTERS = ("ingest_blocks_built_total",)
+# Parallel-ingest metric contract: the block and carrier counters carry
+# a mode label ("native"/"python" — which path built the block or
+# gathered the carriers), and the build-latency histogram exposes the
+# full Prometheus triplet. Checked only when present, like the wire
+# metrics.
+_INGEST_COUNTERS = (
+    "ingest_blocks_built_total",
+    "ingest_carriers_gathered_total",
+)
 _INGEST_HISTOGRAM = "ingest_block_build_seconds"
 
 # Serving/resilience metric contract: each of these counters must carry

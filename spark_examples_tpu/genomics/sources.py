@@ -1096,6 +1096,27 @@ def _load_sidecar_mmap(path: str):
         return None
 
 
+def _native_gather_layout(offsets: np.ndarray, ords: np.ndarray) -> bool:
+    """True when the sidecar's CSR arrays have the layout the native
+    gather reads in place: contiguous native-endian int64 offsets and
+    int32 ordinals (every sidecar this module writes)."""
+    return (
+        offsets.dtype == np.int64
+        and ords.dtype == np.int32
+        and offsets.flags.c_contiguous
+        and ords.flags.c_contiguous
+    )
+
+
+def _record_carriers_gathered(mode: str, n: int) -> None:
+    from spark_examples_tpu import obs
+
+    obs.get_registry().counter(
+        "ingest_carriers_gathered_total",
+        "Carriers gathered from the CSR sidecar into shard CSR pairs",
+    ).labels(mode=mode).inc(n)
+
+
 class _CsrCohort:
     """Columnar CSR sidecar for a JSONL cohort — parse once, mmap forever.
 
@@ -1604,7 +1625,8 @@ class _CsrCohort:
 
     def carrying_csr(self, shard, indexes, variant_set_id, stats, min_af):
         """The shard's carrying lists as one CSR pair (indices, offsets)
-        — numpy end to end, no per-variant Python lists.
+        — no per-variant Python lists: one native pass when the library
+        loads (:meth:`_gather_native`), numpy otherwise.
 
         Row semantics are exactly :meth:`carrying` (keep_empty=False:
         variants with no carriers are dropped); profiling the warm
@@ -1621,6 +1643,11 @@ class _CsrCohort:
         d = self._d
         offsets = d["offsets"]
         rows = a + np.nonzero(keep)[0]
+        from spark_examples_tpu.native import load
+
+        lib = load()
+        if lib is not None and _native_gather_layout(offsets, d["ords"]):
+            return self._gather_native(lib, rows, lookup)
         lo = offsets[rows]
         lens = offsets[rows + 1] - lo
         nonempty = lens > 0
@@ -1638,7 +1665,53 @@ class _CsrCohort:
         if (mapped < 0).any():
             bad = int(d["ords"][pos][mapped < 0][0])
             raise KeyError(str(d["callset_ids"][bad]))
+        _record_carriers_gathered("python", mapped.size)
         return mapped, out_offs
+
+    def _gather_native(self, lib, rows, lookup):
+        """:meth:`carrying_csr`'s ragged gather + ordinal lookup as one
+        native pass (``gather_shard_csr``), byte-identical to the numpy
+        path. The per-carrier numpy passes held the GIL across every
+        fetch worker, starving the dispatch and feed threads; the ctypes
+        call releases it for the whole loop. ``ords`` is read in place
+        from the sidecar's mmap view."""
+        d = self._d
+        offsets, ords = d["offsets"], d["ords"]
+        rows = np.ascontiguousarray(rows, dtype=np.int64)
+        lens = offsets[rows + 1] - offsets[rows]
+        total = int(lens.sum())
+        if total == 0:
+            return None
+        indices = np.empty(total, dtype=np.int64)
+        out_offs = np.empty(np.count_nonzero(lens) + 1, dtype=np.int64)
+        bad = lib.gather_shard_csr(
+            offsets.ctypes.data,
+            rows.ctypes.data,
+            rows.size,
+            ords.ctypes.data,
+            ords.size,
+            lookup.ctypes.data,
+            lookup.size,
+            indices.ctypes.data,
+            indices.size,
+            out_offs.ctypes.data,
+            out_offs.size,
+        )
+        if bad == -2:
+            raise ValueError(
+                "sidecar CSR offsets do not describe ranges of its "
+                "ordinals (corrupt sidecar)"
+            )
+        if bad >= 0:
+            ordinal = int(ords[bad])
+            if not 0 <= ordinal < lookup.size:
+                raise IndexError(
+                    f"index {ordinal} is out of bounds for axis 0 with "
+                    f"size {lookup.size}"
+                )
+            raise KeyError(str(d["callset_ids"][ordinal]))
+        _record_carriers_gathered("native", total)
+        return indices, out_offs
 
     def _rows(self, shard, indexes, variant_set_id, stats, min_af,
               keep_empty):

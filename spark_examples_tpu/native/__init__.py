@@ -178,6 +178,20 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_void_p,
     ]
     lib.csr_to_packed_blocks.restype = ctypes.c_int64
+    lib.gather_shard_csr.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_void_p,
+        ctypes.c_int64,
+        ctypes.c_void_p,
+        ctypes.c_int64,
+        ctypes.c_void_p,
+        ctypes.c_int64,
+        ctypes.c_void_p,
+        ctypes.c_int64,
+        ctypes.c_void_p,
+        ctypes.c_int64,
+    ]
+    lib.gather_shard_csr.restype = ctypes.c_int64
     lib.murmur3_x64_128.argtypes = [
         ctypes.c_void_p,
         ctypes.c_int64,

@@ -66,6 +66,54 @@ int64_t csr_to_packed_blocks(const int64_t* indices, const int64_t* offsets,
   return 0;
 }
 
+// One sidecar shard's carrying lists as a CSR pair, in one pass: for each
+// kept row r = rows[i], the callset ordinals ords[offsets[r] ..
+// offsets[r+1]) are mapped through lookup (ordinal -> dense sample index)
+// into out_indices, and rows with no carriers are dropped from
+// out_offsets (out_offsets[0] = 0; one more entry per non-empty row).
+// The caller sizes out_indices (n_indices) to the kept rows' carrier
+// total and out_offsets (n_offsets) to the non-empty rows + 1.
+// offsets and ords arrive as raw bytes because the sidecar's mmap views
+// need not be aligned to their element size; memcpy loads are
+// alignment-free and compile to plain moves.
+// Returns -1; or -2 when a row's range is not inside ords or the output
+// would overrun its sizes (a corrupt sidecar: the sidecar may come from
+// a mirror's server, so a bad offset must never read or write out of
+// bounds); or the ords position of the first ordinal outside
+// [0, n_lookup) or whose lookup is negative (an unknown callset). The
+// caller raises on any but -1.
+int64_t gather_shard_csr(const uint8_t* offsets, const int64_t* rows,
+                         int64_t n_rows, const uint8_t* ords, int64_t n_ords,
+                         const int64_t* lookup, int64_t n_lookup,
+                         int64_t* out_indices, int64_t n_indices,
+                         int64_t* out_offsets, int64_t n_offsets) {
+  int64_t k = 0;
+  int64_t n_out = 0;
+  out_offsets[0] = 0;
+  for (int64_t i = 0; i < n_rows; ++i) {
+    int64_t lo, hi;
+    std::memcpy(&lo, offsets + 8 * rows[i], 8);
+    std::memcpy(&hi, offsets + 8 * (rows[i] + 1), 8);
+    if (hi == lo) {
+      continue;
+    }
+    if (lo < 0 || hi < lo || hi > n_ords || hi - lo > n_indices - k ||
+        n_out + 1 >= n_offsets) {
+      return -2;
+    }
+    for (int64_t p = lo; p < hi; ++p) {
+      int32_t o;
+      std::memcpy(&o, ords + 4 * p, 4);
+      if (o < 0 || o >= n_lookup || lookup[o] < 0) {
+        return p;
+      }
+      out_indices[k++] = lookup[o];
+    }
+    out_offsets[++n_out] = k;
+  }
+  return -1;
+}
+
 static inline uint64_t rotl64(uint64_t x, int8_t r) {
   return (x << r) | (x >> (64 - r));
 }

@@ -253,6 +253,198 @@ class TestNativeCohortParser:
                 np.testing.assert_array_equal(a, b, err_msg=name)
 
 
+def _gather_cohort(seed=0, nv=400, ns=64, unaligned=False):
+    """A hand-built CSR sidecar: empty rows, two variant sets, NaN AFs.
+    ``unaligned`` serves offsets and ords as views one byte off their
+    element alignment, as an mmap'd npz member may be."""
+    from spark_examples_tpu.genomics.sources import _CsrCohort
+
+    rng = np.random.default_rng(seed)
+    lens = rng.integers(0, 12, nv)
+    lens[rng.random(nv) < 0.25] = 0
+    offsets = np.zeros(nv + 1, np.int64)
+    np.cumsum(lens, out=offsets[1:])
+    ords = rng.integers(0, ns, int(offsets[-1])).astype(np.int32)
+    afs = rng.random(nv)
+    afs[rng.random(nv) < 0.1] = np.nan
+    if unaligned:
+        def shifted(a):
+            buf = np.zeros(a.nbytes + 1, np.uint8)
+            view = np.frombuffer(buf.data, a.dtype, a.size, offset=1)
+            view[:] = a
+            return view
+
+        offsets, ords = shifted(offsets), shifted(ords)
+        assert not ords.flags.aligned and not offsets.flags.aligned
+    data = {
+        "contigs": np.array(["20"]),
+        "seg_lo": np.array([0], np.int64),
+        "seg_hi": np.array([nv], np.int64),
+        "starts": np.arange(nv, dtype=np.int64) * 10,
+        "vcode": rng.integers(0, 2, nv).astype(np.int32),
+        "afs": afs,
+        "offsets": offsets,
+        "ords": ords,
+        "vsids": np.array(["vs0", "vs1"]),
+        "callset_ids": np.array([f"s{i}" for i in range(ns)]),
+    }
+    return _CsrCohort(data), {f"s{i}": i for i in range(ns)}
+
+
+def _gather_both(monkeypatch, cohort, shard, indexes, vsid="", min_af=None,
+                 errors=(KeyError, IndexError)):
+    """carrying_csr on the native path, then on the numpy fallback."""
+    from spark_examples_tpu import native
+    from spark_examples_tpu.utils.stats import IoStats
+
+    got = []
+    for lib in (native.load(), None):
+        with monkeypatch.context() as m:
+            m.setattr(native, "load", lambda lib=lib: lib)
+            try:
+                got.append(
+                    cohort.carrying_csr(
+                        shard, indexes, vsid, IoStats(), min_af
+                    )
+                )
+            except errors as e:
+                got.append(e)
+    return got
+
+
+class TestShardGather:
+    """``gather_shard_csr``: the sidecar shard's ragged gather and
+    ordinal lookup in one native pass, byte-identical to the numpy
+    path it replaces in ``_CsrCohort.carrying_csr``."""
+
+    @pytest.mark.parametrize(
+        "case",
+        [
+            "whole_contig",
+            "sub_window",
+            "vsid_filter",
+            "min_af_filter",
+            "vsid_and_min_af",
+            "one_row",
+            "empty_window",
+            "all_rows_empty",
+            "restricted_indexes",
+            "unaligned_views",
+        ],
+    )
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_pair_matches_numpy(self, monkeypatch, case, seed):
+        from spark_examples_tpu.genomics.shards import Shard
+
+        cohort, indexes = _gather_cohort(
+            seed, unaligned=case == "unaligned_views"
+        )
+        d = cohort._d
+        nv = d["starts"].size
+        shard, vsid, min_af = Shard("chr20", 0, nv * 10), "", None
+        if case == "sub_window":
+            shard = Shard("20", 1005, 2995)
+        elif case == "vsid_filter":
+            vsid = "vs1"
+        elif case == "min_af_filter":
+            min_af = 0.4
+        elif case == "vsid_and_min_af":
+            vsid, min_af = "vs0", 0.2
+        elif case == "one_row":
+            row = int(np.flatnonzero(np.diff(d["offsets"]))[seed])
+            shard = Shard("20", row * 10, row * 10 + 1)
+        elif case == "empty_window":
+            shard = Shard("21", 0, nv * 10)
+        elif case == "all_rows_empty":
+            empty = np.flatnonzero(np.diff(d["offsets"]) == 0)
+            run = empty[np.flatnonzero(np.diff(empty) == 1)[0]]
+            shard = Shard("20", int(run) * 10, int(run) * 10 + 11)
+        elif case == "restricted_indexes":
+            lo, hi = d["offsets"][100], d["offsets"][200]
+            used = np.unique(d["ords"][lo:hi])
+            perm = np.random.default_rng(seed).permutation(used.size)
+            indexes = {f"s{o}": int(p) for o, p in zip(used, perm)}
+            shard = Shard("20", 1000, 2000)
+        native, python = _gather_both(
+            monkeypatch, cohort, shard, indexes, vsid, min_af
+        )
+        if case in ("empty_window", "all_rows_empty"):
+            assert native is None and python is None
+            return
+        assert python is not None
+        for n_arr, p_arr in zip(native, python):
+            assert n_arr.dtype == p_arr.dtype == np.int64
+            assert n_arr.tobytes() == p_arr.tobytes()
+
+    def test_unknown_callset_raises_same_key_error(self, monkeypatch):
+        from spark_examples_tpu.genomics.shards import Shard
+
+        cohort, indexes = _gather_cohort(3)
+        d = cohort._d
+        first = int(d["ords"][d["offsets"][50]])
+        del indexes[f"s{first}"]
+        native, python = _gather_both(
+            monkeypatch, cohort, Shard("20", 500, 4000), indexes
+        )
+        assert type(native) is type(python) is KeyError
+        assert native.args == python.args
+
+    def test_ordinal_past_callsets_raises_index_error(self, monkeypatch):
+        from spark_examples_tpu.genomics.shards import Shard
+
+        cohort, indexes = _gather_cohort(5)
+        d = cohort._d
+        d["ords"][d["offsets"][60]] = d["callset_ids"].size + 3
+        native, python = _gather_both(
+            monkeypatch, cohort, Shard("20", 500, 4000), indexes
+        )
+        assert type(native) is type(python) is IndexError
+        assert str(native) == str(python)
+
+    @pytest.mark.parametrize("fault", ["past_ords", "decreasing"])
+    def test_corrupt_offsets_raise_without_overrun(self, monkeypatch, fault):
+        """A sidecar may come from a mirror's server: offsets that do not
+        describe ranges of its ordinals make the native pass raise before
+        it reads or writes out of bounds. (The numpy path raises on
+        ranges past the ordinals and drops a decreasing row.)"""
+        from spark_examples_tpu.genomics.shards import Shard
+
+        cohort, indexes = _gather_cohort(4)
+        offsets = cohort._d["offsets"].copy()
+        row = int(np.flatnonzero(np.diff(offsets))[10])
+        if fault == "past_ords":
+            offsets[row + 1 :] += cohort._d["ords"].size
+        else:
+            offsets[row + 1] = offsets[row] - 1
+        cohort._d["offsets"] = offsets
+        native, python = _gather_both(
+            monkeypatch, cohort, Shard("20", 0, 4000), indexes,
+            errors=(KeyError, IndexError, ValueError),
+        )
+        assert isinstance(native, ValueError)
+        assert "corrupt sidecar" in str(native)
+        if fault == "past_ords":
+            assert isinstance(python, IndexError)
+
+    def test_sidecar_mmap_views_match_numpy(self, monkeypatch, tmp_path):
+        """Through a real on-disk sidecar, read in place from its mmap."""
+        from spark_examples_tpu.genomics.fixtures import synthetic_cohort
+        from spark_examples_tpu.genomics.shards import Shard
+        from spark_examples_tpu.genomics.sources import JsonlSource
+
+        root = str(tmp_path / "cohort")
+        synthetic_cohort(12, 90, seed=3).dump(root)
+        source = JsonlSource(root)
+        indexes = {c.id: i for i, c in enumerate(source.list_callsets(""))}
+        cohort = source._ensure_csr()
+        native, python = _gather_both(
+            monkeypatch, cohort, Shard("17", 41196311, 41277499), indexes
+        )
+        assert native[0].size > 0
+        for n_arr, p_arr in zip(native, python):
+            assert n_arr.tobytes() == p_arr.tobytes()
+
+
 class TestNativeSoOverride:
     """SPARK_EXAMPLES_TPU_NATIVE_SO (the sanitizer-gate injection seam,
     scripts/sanitize_native.sh): a valid override loads and binds; an

@@ -28,6 +28,7 @@ import threading
 import pytest
 
 from spark_examples_tpu import obs
+from spark_examples_tpu.native import native_available
 from spark_examples_tpu.obs.metrics import MetricsRegistry
 from spark_examples_tpu.obs.session import TelemetrySession
 from spark_examples_tpu.obs.tracer import SpanTracer
@@ -572,6 +573,57 @@ class TestIngestSpanEmission:
         assert "ingest_block_build_seconds_sum" in prom
         assert "ingest_block_build_seconds_count" in prom
 
+    def test_carriers_gathered_counter_has_mode_label(self, run_artifacts):
+        prom = open(run_artifacts["metrics"]).read()
+        lines = [
+            ln
+            for ln in prom.splitlines()
+            if ln.startswith("ingest_carriers_gathered_total")
+        ]
+        assert lines and all('mode="' in ln for ln in lines)
+        assert sum(float(ln.rsplit(" ", 1)[1]) for ln in lines) > 0
+
+    @pytest.mark.parametrize(
+        "mode",
+        [
+            pytest.param(
+                "native",
+                marks=pytest.mark.skipif(
+                    not native_available(),
+                    reason="native toolchain unavailable",
+                ),
+            ),
+            "python",
+        ],
+    )
+    def test_carriers_gathered_counts_the_path_taken(
+        self, mode, tmp_path, monkeypatch
+    ):
+        """``ingest_carriers_gathered_total{mode}`` counts every carrier
+        of a sidecar shard under the path that gathered it: native when
+        the library loads, python when ``load()`` returns None."""
+        from spark_examples_tpu import native
+        from spark_examples_tpu.genomics.fixtures import synthetic_cohort
+        from spark_examples_tpu.genomics.shards import Shard
+        from spark_examples_tpu.genomics.sources import JsonlSource
+
+        root = str(tmp_path / "cohort")
+        synthetic_cohort(10, 60, seed=3).dump(root)
+        source = JsonlSource(root)
+        index = {c.id: i for i, c in enumerate(source.list_callsets(""))}
+        if mode == "python":
+            monkeypatch.setattr(native, "load", lambda: None)
+        with TelemetrySession() as s:
+            indices, _offsets = source.stream_carrying_csr(
+                "", Shard("17", 41196311, 41277499), index, None
+            )
+        counters = s.registry.snapshot()["counters"]
+        key = 'ingest_carriers_gathered_total{mode="%s"}'
+        other = "python" if mode == "native" else "native"
+        assert indices.size > 0
+        assert counters[key % mode] == indices.size
+        assert counters.get(key % other, 0) == 0
+
     def test_artifacts_pass_ingest_schema_checks(self, run_artifacts):
         assert validate.validate_trace(run_artifacts["trace"]) == []
         assert validate.validate_metrics(run_artifacts["metrics"]) == []
@@ -604,6 +656,16 @@ class TestIngestSpanEmission:
             "# HELP ingest_blocks_built_total blocks\n"
             "# TYPE ingest_blocks_built_total counter\n"
             "ingest_blocks_built_total 5\n"
+        )
+        errs = validate.validate_metrics(str(path))
+        assert errs and "mode" in errs[0]
+
+    def test_validator_rejects_modeless_carriers_counter(self, tmp_path):
+        path = tmp_path / "bad.metrics.prom"
+        path.write_text(
+            "# HELP ingest_carriers_gathered_total carriers\n"
+            "# TYPE ingest_carriers_gathered_total counter\n"
+            "ingest_carriers_gathered_total 40\n"
         )
         errs = validate.validate_metrics(str(path))
         assert errs and "mode" in errs[0]
