@@ -16,13 +16,13 @@ at tiny sizes; only :func:`main` insists on a TPU):
 - ``sparse``: a seeded rare-variant cohort at N=4,096 through
   ``--pca-mode sparse``, so windows take the scatter route. G must equal
   the host X·Xᵀ exactly, and on the chip every scatter window must take
-  the compiled Pallas kernel or the MXU update (as its shape decides),
-  none the scan.
+  an int8 MXU update (the compare-chain or the scatter-built panel, as
+  its carrier bucket decides), none the scan.
 - ``mesh`` (``--chips 4``): the same structured cohort on
   ``--mesh-shape data:2,model:2``, sample-sharded dense and
-  ``--pca-mode sparse``, plus the rare-variant cohort through the sharded
-  scatter tiles: G exact, coordinates against the golden, and G's shards
-  on every device of the mesh.
+  ``--pca-mode sparse``, plus the rare-variant cohort through the mesh
+  tiles' int8 MXU update: G exact, coordinates against the golden, and
+  G's shards on every device of the mesh.
 
 Every time printed is a smoke timing of one cold run (compiles included),
 not a metric. The last line of stdout is the JSON result the driver reads;
@@ -139,11 +139,24 @@ def _parity(source, result, g_ref) -> float:
 
 def _scatter_counts() -> dict:
     from spark_examples_tpu import obs
+    from spark_examples_tpu.ops.sparse import SCATTER_PATHS
 
     counter = obs.get_registry().counter("sparse_scatter_path_windows_total")
+    return {p: counter.labels(path=p).value for p in SCATTER_PATHS}
+
+
+def _chip_paths(single_device: bool) -> set:
+    """The updates the scatter rule gives, on a TPU, a window whose
+    carrier counts fit int8 (the smoke's cohorts repeat no carrier),
+    whatever its carrier bucket."""
+    from spark_examples_tpu.ops.sparse import (
+        MXU_MAX_CARRIER_BUCKET,
+        scatter_path_rule,
+    )
+
     return {
-        p: counter.labels(path=p).value
-        for p in ("pallas", "interpret", "scan", "mxu", "fallback-scan")
+        scatter_path_rule("tpu", k, True, single_device)
+        for k in (MXU_MAX_CARRIER_BUCKET, MXU_MAX_CARRIER_BUCKET + 1)
     }
 
 
@@ -207,13 +220,10 @@ def _finish_that_ran(timer) -> str:
 
 def _run_sparse(name, source, argv, g_ref, clock, require_chip_path,
                 mesh=None, n_devices=None):
-    """Sparse-engine ingest; G exact, scatter path named and counted.
-    With ``require_chip_path``, every window took a path of the chip: the
-    compiled Pallas kernel, or (single device only) the MXU update —
-    never the scan, and never a fall to it."""
-    from spark_examples_tpu.ops.scatter_kernel import resolve_scatter_path
-
-    n = g_ref.shape[0]
+    """Sparse-engine ingest; G exact, scatter windows counted by path.
+    With ``require_chip_path``, every window took an int8 MXU update the
+    rule gives on a TPU (one chip: ``mxu`` or ``mxu-tile``; a mesh:
+    ``mxu-tile``) — never the scan."""
     before = _scatter_counts()
     c0 = clock.mark()
     t0 = time.perf_counter()
@@ -234,24 +244,19 @@ def _run_sparse(name, source, argv, g_ref, clock, require_chip_path,
         p: int(v - before[p]) for p, v in _scatter_counts().items()
         if v - before[p]
     }
-    tile = (n, n) if mesh is None else (
-        n // mesh.shape["data"], n // mesh.shape["model"]
-    )
-    path = resolve_scatter_path(tile, np.float32)
-    _say(f"{name}: G equals host X·Xᵀ exactly; scatter path {path} for "
-         f"tile {tile}; scatter windows by path {windows}")
+    _say(f"{name}: G equals host X·Xᵀ exactly; scatter windows by path "
+         f"{windows}")
     _say(f"{name}: smoke timing (not a metric): ingest+gramian {t_g:.2f} s, "
          f"{c1[1] - c0[1]} compiles {c1[0] - c0[0]:.1f} s")
     if not windows:
         raise AssertionError(f"{name}: no window took the scatter route")
-    allowed = {"pallas"} if mesh is not None else {"pallas", "mxu"}
+    allowed = _chip_paths(single_device=mesh is None)
     if require_chip_path and not set(windows) <= allowed:
         raise AssertionError(
-            f"{name}: expected every scatter window on the compiled Pallas "
-            f"kernel{'' if mesh is not None else ' or the MXU update'}, got "
-            f"path {path} and windows {windows}"
+            f"{name}: expected every scatter window on an int8 MXU update "
+            f"{sorted(allowed)}, got windows {windows}"
         )
-    return {"path": path, "windows": windows}
+    return {"windows": windows}
 
 
 def phase_pca(clock, n=PCA_SAMPLES, v=PCA_VARIANTS, seed=PCA_SEED):

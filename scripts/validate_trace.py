@@ -344,8 +344,7 @@ _LABELED_COUNTERS = {
                                           # panel window
     "sparse_gramian_windows_total": "route",  # scatter/dense per window
     "sparse_pod_coalesced_windows_total": "mode",  # gang/solo per step
-    "sparse_scatter_path_windows_total": "path",  # pallas/interpret/scan/
-                                          # mxu/mxu-tile/fallback-scan
+    "sparse_scatter_path_windows_total": "path",  # scan/mxu/mxu-tile
                                           # per window
     "sparse_pod_sync_total": "outcome",   # synced/drained/producer-error/
                                           # route-divergence/dtype-divergence

@@ -19,24 +19,29 @@ bit-packed block.
 
 Formulation: each window's ragged carrier lists are right-padded into a
 ``(V_blk, k_max)`` int32 index matrix with an out-of-range sentinel. The
-device step (``_scatter_accumulate_jit``) then takes one of two forms,
-chosen per window on the host
-(:func:`spark_examples_tpu.ops.scatter_kernel.window_scatter_path`):
+device step (``_scatter_accumulate_jit``) then takes one of three forms,
+chosen per window on the host by :func:`window_scatter_path`:
 
-- the pair scatter: ``+1`` at every ``(idx[v, a], idx[v, b])`` pair with
-  OOB-drop semantics, O(Σ k_v²) work (k_v = carriers of variant v), in
-  fixed-size variant chunks under ``lax.scan`` (or the Pallas one-hot
-  kernel) so the update transient is bounded by ``chunk · k_max²``;
-- the MXU update (single device, TPU, in place of both): the
+- the pair scatter (``scan``, every non-TPU backend, and a TPU window
+  whose counts pass int8): ``+1`` at every ``(idx[v, a], idx[v, b])``
+  pair with OOB-drop semantics, O(Σ k_v²) work (k_v = carriers of
+  variant v), in fixed-size variant chunks under ``lax.scan`` so the
+  update transient is bounded by ``chunk · k_max²`` — the CPU path and
+  the reference the MXU updates are pinned against;
+- the MXU update (``mxu``, single device, TPU, bucket ≤ 127): the
   window's ``(N, V_blk)`` int8 carrier-count panel is built on the
   device by comparing the index matrix against a sample iota
   (:func:`carrier_count_panel`), then ``G += X·Xᵀ`` runs on the int8
   MXU — O(N²·V_blk) work on the unit built for it (on a TPU v5e, 1.4 ms
-  per 3,202 × 8,192 window at k_max = 64, where the scan takes 259 ms).
+  per 3,202 × 8,192 window at k_max = 64, where the scan takes 259 ms);
+- the scatter-built MXU update (``mxu-tile``, TPU, any other window
+  whose counts fit int8): the same panel scatter-built from the
+  window's carrier list (:func:`pair_count_panel`) — the update every
+  mesh tile takes, here over the whole G as one tile.
 
-Sentinel entries vanish and duplicate carriers count multiply in both,
-and every update is an exact integer in f32 (the same exactness argument
-as the dense path — bit-identical G, pinned by tests).
+Sentinel entries vanish and duplicate carriers count multiply in every
+form, and every update is an exact integer in f32 (the same exactness
+argument as the dense path — bit-identical G, pinned by tests).
 
 Density routing: genuinely dense windows (common variants) would pay
 k_max² ≈ (dN)² per variant here while the MXU path pays N·V_blk — the
@@ -60,18 +65,23 @@ import numpy as np
 __all__ = [
     "DEFAULT_POD_COALESCE_VARIANTS",
     "DEFAULT_SPARSE_DENSITY_THRESHOLD",
+    "MXU_MAX_CARRIER_BUCKET",
     "SCATTER_CHUNK_VARIANTS",
+    "SCATTER_PATHS",
     "carrier_count_panel",
     "carrier_counts_fit_int8",
+    "carrier_matrix_counts_fit_int8",
     "carrier_pairs",
     "dense_panel_width",
     "padded_carrier_matrix",
     "pair_count_panel",
+    "scatter_path_rule",
     "scatter_pairs_chunked",
     "sparse_gramian_accumulate",
     "sparse_gramian_blockwise",
     "window_density",
     "window_route",
+    "window_scatter_path",
 ]
 
 # Dense/sparse switch: windows with density STRICTLY below this scatter
@@ -96,8 +106,17 @@ SCATTER_CHUNK_VARIANTS = 256
 # per-window exchange latency. Windows at the normal block width
 # (DEFAULT_BLOCK_VARIANTS) already exceed it — coalescing only engages
 # where it pays. 0/1 disables. Aligned with SCATTER_CHUNK_VARIANTS so a
-# full gang fills at least one scan/kernel chunk.
+# full gang fills at least one scan chunk.
 DEFAULT_POD_COALESCE_VARIANTS = 256
+
+# The MXU updates hold per-(sample, variant) carrier counts in int8: a
+# count is at most the carrier bucket, so a bucket within this fits by
+# its width alone.
+MXU_MAX_CARRIER_BUCKET = 127
+
+# The updates a scatter-route window can take (:func:`scatter_path_rule`):
+# the ``path`` labels of ``sparse_scatter_path_windows_total``.
+SCATTER_PATHS = ("scan", "mxu", "mxu-tile")
 
 _MIN_CARRIER_BUCKET = 8
 
@@ -136,6 +155,51 @@ def window_route(
     ):
         return "dense"
     return "scatter"
+
+
+def scatter_path_rule(
+    backend: str, k: int, counts_fit_int8: bool, single_device: bool
+) -> str:
+    """``"scan" | "mxu" | "mxu-tile"``: the update a scatter-route window
+    takes on ``backend`` — the one rule of the scatter route.
+
+    On a TPU a window whose (sample, site) carrier counts fit int8 takes
+    an int8 MXU panel update: a single-device window with carrier bucket
+    ``k`` at most :data:`MXU_MAX_CARRIER_BUCKET` the compare-chain panel
+    (``"mxu"``), any other (a larger bucket, a host-local or a pod mesh
+    tile) the panels scatter-built from its carriers (``"mxu-tile"``).
+    A window whose counts pass int8, and every window off a TPU, takes
+    the chunked scan.
+    """
+    if backend != "tpu" or not counts_fit_int8:
+        return "scan"
+    if single_device and k <= MXU_MAX_CARRIER_BUCKET:
+        return "mxu"
+    return "mxu-tile"
+
+
+def window_scatter_path(
+    k: int, counts_fit_int8: bool, single_device: bool = False
+) -> str:
+    """:func:`scatter_path_rule` on this process's backend, counted in
+    ``sparse_scatter_path_windows_total{path}`` — host side, once per
+    window, by the single-device, host-local mesh and pod accumulators
+    alike. Every process of a pod passes values it holds alike (the
+    agreed bucket, the check on the gathered carrier matrix), so their
+    device programs stay congruent."""
+    from spark_examples_tpu import obs
+
+    path = scatter_path_rule(
+        jax.default_backend(), k, counts_fit_int8, single_device
+    )
+    obs.get_registry().counter(
+        "sparse_scatter_path_windows_total",
+        "Scatter-route windows by the update they took (scan = the "
+        "chunked lax.scan; mxu = the compare-chain carrier-count panel "
+        "on the int8 MXU; mxu-tile = carrier-count panels scatter-built "
+        "from the carriers, on the int8 MXU)",
+    ).labels(path=path).inc()
+    return path
 
 
 def _carrier_bucket(k: int) -> int:
@@ -258,13 +322,14 @@ def carrier_count_panel(idx, n_rows: int):
     row and drop out, as the scatter's ``mode="drop"`` does; a duplicate
     carrier counts more than once, as scatter-add does — so ``X·Xᵀ`` is
     the pair scatter's ``Σ_v r_v r_vᵀ`` exactly. A count is at most k,
-    which the dispatch keeps within int8
-    (:data:`spark_examples_tpu.ops.scatter_kernel.MXU_MAX_CARRIER_BUCKET`).
+    which the rule keeps within int8 (:data:`MXU_MAX_CARRIER_BUCKET`).
 
     The sum is a chain of k elementwise adds that fuses into one loop: on
     a TPU v5e it builds a 3,202 × 8,192 panel from k = 64 in 0.94 ms,
     where a ``reduce`` over the broadcast (k, N, V) compare takes
-    1.47 ms and an ``.at[].add`` scatter of the V·k entries 4.42 ms.
+    1.47 ms and an ``.at[].add`` scatter of the V·k entries 4.42 ms —
+    why a single-device window within the bucket keeps this build rather
+    than :func:`pair_count_panel`'s.
     """
     rows = jax.lax.broadcasted_iota(jnp.int32, (n_rows, idx.shape[0]), 0)
     x = jnp.zeros(rows.shape, jnp.int8)
@@ -303,17 +368,12 @@ def carrier_counts_fit_int8(
     """Does every (sample, site) carrier count of the window fit int8?
 
     A count is a carrier's repeats within one site, so only a site with
-    more than :data:`~spark_examples_tpu.ops.scatter_kernel.
-    MXU_MAX_CARRIER_BUCKET` carriers can pass it, and only if some
-    carrier repeats more than that many times there. Those sites'
-    entries are sorted by (site, sample) and the longest run of one
-    key is the largest count; every other site is within int8 by its
-    length alone.
+    more than :data:`MXU_MAX_CARRIER_BUCKET` carriers can pass it, and
+    only if some carrier repeats more than that many times there. Those
+    sites' entries are sorted by (site, sample) and the longest run of
+    one key is the largest count; every other site is within int8 by
+    its length alone.
     """
-    from spark_examples_tpu.ops.scatter_kernel import (
-        MXU_MAX_CARRIER_BUCKET,
-    )
-
     lens = np.asarray(lens, dtype=np.int64)
     big = lens > MXU_MAX_CARRIER_BUCKET
     if not big.any():
@@ -327,6 +387,18 @@ def carrier_counts_fit_int8(
         return True  # no carrier repeats within a site
     runs = np.diff(np.concatenate([[0], edges, [keys.size]]))
     return int(runs.max()) <= MXU_MAX_CARRIER_BUCKET
+
+
+def carrier_matrix_counts_fit_int8(mat: np.ndarray, n_samples: int) -> bool:
+    """:func:`carrier_counts_fit_int8` for a padded carrier matrix
+    (:func:`padded_carrier_matrix`, or a pod step's gathered matrix),
+    whose entries at or past ``n_samples`` are pad. A bucket of at most
+    :data:`MXU_MAX_CARRIER_BUCKET` fits by its width alone."""
+    mat = np.asarray(mat)
+    if mat.shape[1] <= MXU_MAX_CARRIER_BUCKET:
+        return True
+    live = mat < n_samples
+    return carrier_counts_fit_int8(mat[live], live.sum(axis=1), n_samples)
 
 
 def pair_count_panel(samples, sites, n_rows: int, n_cols: int):
@@ -350,34 +422,35 @@ def pair_count_panel(samples, sites, n_rows: int, n_cols: int):
 @partial(
     jax.jit,
     donate_argnums=(0,),
-    static_argnames=("path", "compute_dtype"),
+    static_argnames=("path", "compute_dtype", "n_cols"),
 )
-def _scatter_accumulate_jit(g, idx, path="scan", compute_dtype=None):
+def _scatter_accumulate_jit(
+    g, carriers, path="scan", compute_dtype=None, n_cols=None
+):
     """``g[idx[v,a], idx[v,b]] += 1`` for every (v, a, b) — OOB dropped.
 
-    ``path`` is the pre-resolved implementation
-    (:func:`spark_examples_tpu.ops.scatter_kernel.window_scatter_path`):
-    the chunked-scan body, the Pallas one-hot-count kernel (compiled /
-    interpreter mode), or ``"mxu"`` — the carrier-count panel built on
-    the device and contracted on the MXU in ``compute_dtype``
+    ``path`` is the window's update (:func:`window_scatter_path`): the
+    chunked scan or the compare-chain panel (``"mxu"``) of the padded
+    carrier matrix ``carriers``, or the panel scatter-built from the
+    ``(2, E)`` carrier list ``carriers`` (:func:`carrier_pairs`) over
+    ``n_cols`` sites (``"mxu-tile"``); the panels are contracted on the
+    MXU in ``compute_dtype``
     (:func:`spark_examples_tpu.ops.gramian.mxu_cross_product`) —
     bit-identical whichever.
     """
     if path == "scan":
-        return scatter_pairs_chunked(g, idx, idx)
+        return scatter_pairs_chunked(g, carriers, carriers)
+    from spark_examples_tpu.ops.gramian import mxu_cross_product
+
     if path == "mxu":
-        from spark_examples_tpu.ops.gramian import mxu_cross_product
-
-        # The barrier keeps the panel build out of the contraction's
-        # fusion: fused, the TPU recomputes the panel for every output
-        # tile (XLA's cost model: about 4× the two steps apart).
-        x = jax.lax.optimization_barrier(
-            carrier_count_panel(idx, g.shape[0])
-        )
-        return g + mxu_cross_product(x, g.dtype, compute_dtype)
-    from spark_examples_tpu.ops.scatter_kernel import scatter_pairs_kernel
-
-    return scatter_pairs_kernel(g, idx, idx, interpret=path == "interpret")
+        x = carrier_count_panel(carriers, g.shape[0])
+    else:
+        x = pair_count_panel(carriers[0], carriers[1], g.shape[0], n_cols)
+    # The barrier keeps the panel build out of the contraction's
+    # fusion: fused, the TPU recomputes the panel for every output
+    # tile (XLA's cost model: about 4× the two steps apart).
+    x = jax.lax.optimization_barrier(x)
+    return g + mxu_cross_product(x, g.dtype, compute_dtype)
 
 
 def _pad_rows_for_scan(rows: int) -> int:
@@ -387,41 +460,42 @@ def _pad_rows_for_scan(rows: int) -> int:
     return round_up_multiple(max(rows, 1), SCATTER_CHUNK_VARIANTS)
 
 
-def sparse_gramian_accumulate(g, window_idx, lens, scatter_path=None):
+def sparse_gramian_accumulate(g, window_idx, lens):
     """One sparse accumulation step: scatter a CSR window into G.
 
     ``g`` is the ``(N, N)`` device accumulator (donated — updates in
     place in device memory); the window is host CSR ``(indices, lens)``.
     Returns the updated G. Bit-identical to densifying the window and
-    running ``gramian_accumulate`` (pinned by tests). ``scatter_path``
-    pre-resolves the scan-vs-Pallas-kernel choice for streams that
-    dispatch many windows (resolved per call here when ``None``); on a
-    TPU a window whose bucket fits int8 takes the MXU update instead.
+    running ``gramian_accumulate`` (pinned by tests), whichever update
+    :func:`window_scatter_path` picks for it.
     """
     from spark_examples_tpu.ops.gramian import resolve_gramian_compute_dtype
-    from spark_examples_tpu.ops.scatter_kernel import (
-        resolve_scatter_path,
-        window_scatter_path,
-    )
 
-    if scatter_path is None:
-        scatter_path = resolve_scatter_path(g.shape, g.dtype)
-    idx = padded_carrier_matrix(
-        window_idx,
-        lens,
-        sentinel=g.shape[0],
-        n_rows=_pad_rows_for_scan(np.asarray(lens).size),
-    )
+    lens = np.asarray(lens)
+    n = g.shape[0]
+    n_rows = _pad_rows_for_scan(lens.size)
     path = window_scatter_path(
-        scatter_path, g.shape, idx.shape[1], single_device=True
+        _carrier_bucket(int(lens.max()) if lens.size else 0),
+        carrier_counts_fit_int8(window_idx, lens, n),
+        single_device=True,
     )
+    if path == "mxu-tile":
+        carriers = carrier_pairs(window_idx, lens, sentinel=n)
+    else:
+        carriers = padded_carrier_matrix(
+            window_idx, lens, sentinel=n, n_rows=n_rows
+        )
     compute_dtype = (
-        resolve_gramian_compute_dtype(jnp.int8, g.dtype)
-        if path == "mxu"
-        else None
+        None
+        if path == "scan"
+        else resolve_gramian_compute_dtype(jnp.int8, g.dtype)
     )
     return _scatter_accumulate_jit(
-        g, idx, path=path, compute_dtype=compute_dtype
+        g,
+        carriers,
+        path=path,
+        compute_dtype=compute_dtype,
+        n_cols=n_rows if path == "mxu-tile" else None,
     )
 
 
@@ -505,17 +579,10 @@ def sparse_gramian_blockwise(
         pack_indicator_block,
     )
 
-    from spark_examples_tpu.ops.scatter_kernel import resolve_scatter_path
-
     width = block_variants or DEFAULT_BLOCK_VARIANTS
     g = jnp.zeros((n_samples, n_samples), dtype=accum_dtype)
     if device is not None:
         g = jax.device_put(g, device)
-    # One scan-vs-kernel resolution per stream (outside any trace), so
-    # the whole accumulation rides one executable family.
-    scatter_path = resolve_scatter_path(
-        (n_samples, n_samples), np.dtype(accum_dtype)
-    )
     windows = iter(windows)
     with obs.span("gramian.sparse.accumulate", n=n_samples):
         while True:
@@ -537,9 +604,7 @@ def sparse_gramian_blockwise(
                 variants=int(lens.size),
             ):
                 if route == "scatter":
-                    g = sparse_gramian_accumulate(
-                        g, window_idx, lens, scatter_path=scatter_path
-                    )
+                    g = sparse_gramian_accumulate(g, window_idx, lens)
                 else:
                     dense_width = dense_panel_width(
                         int(lens.size), width

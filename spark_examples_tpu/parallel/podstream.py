@@ -654,6 +654,8 @@ class PodSlot:
     nnz: int
     variants: int
     windows: int  # local windows coalesced into this step's gang
+    # scatter: every carrier count of ``gathered`` fits int8
+    counts_fit_int8: bool = False
 
 
 _DONE = object()

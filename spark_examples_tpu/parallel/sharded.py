@@ -618,24 +618,17 @@ def _sparse_tile_kernels(
     tile_cols: int,
     accum_name: str,
     compute_name: str,
-    scatter_path: str = "scan",
     mirror: bool = False,
 ):
-    """Compiled kernel set (tile scatter, GSPMD dense fallback, pod
-    dense tile step, symmetric-mirror finalizer, int8 MXU tile update)
-    for one (mesh, padded-N, dtype, scatter-path, mirror) geometry —
-    cached on the
-    hashable geometry key. ``jax.jit`` caches by function identity, so
+    """Compiled kernel set (tile scan, GSPMD dense fallback, pod dense
+    tile step, symmetric-mirror finalizer, int8 MXU tile update) for one
+    (mesh, padded-N, dtype, mirror) geometry — cached on the hashable
+    geometry key. ``jax.jit`` caches by function identity, so
     building these as fresh closures per accumulation call would
     re-trace and re-compile the shard_map program on EVERY call (the
     bench sweep's repeats and per-job driver runs would measure XLA
     compilation, not accumulation); the lru_cache pins one executable
     per geometry.
-
-    ``scatter_path`` is the pre-resolved scan-vs-Pallas choice
-    (:func:`spark_examples_tpu.ops.scatter_kernel.resolve_scatter_path`,
-    resolved OUTSIDE the trace by the accumulator entry point) — part of
-    the cache key so the env kill switch takes effect per stream.
 
     ``mirror=True`` (square tile grids on the pod path) exploits G's
     symmetry: an off-diagonal tile is exactly its transpose partner's
@@ -648,12 +641,12 @@ def _sparse_tile_kernels(
     grid this removes the g(g−1)/2 redundant off-diagonal tile
     computations the pair-space tiling otherwise duplicates across the
     diagonal: the dense route halves its off-diagonal MXU work
-    (scatter updates are index-driven, so there the masking only keeps
-    the partition consistent). The all_gather stays unconditional on
-    every device — no collective ever sits inside a skipped branch.
+    (the scan and MXU tile updates are index-driven, so there the
+    masking only keeps the partition consistent). The all_gather stays
+    unconditional on every device — no collective ever sits inside a
+    skipped branch.
     """
     from spark_examples_tpu.ops.gramian import mxu_cross_product_pair
-    from spark_examples_tpu.ops.scatter_kernel import scatter_pairs_kernel
     from spark_examples_tpu.ops.sparse import (
         pair_count_panel,
         scatter_pairs_chunked,
@@ -671,13 +664,6 @@ def _sparse_tile_kernels(
         )
         return d_idx, m_idx
 
-    def _scatter_impl(g_tile, li, lj):
-        if scatter_path == "scan":
-            return scatter_pairs_chunked(g_tile, li, lj)
-        return scatter_pairs_kernel(
-            g_tile, li, lj, interpret=scatter_path == "interpret"
-        )
-
     half = tile_rows // 2  # mirror slab split (tiles square there)
 
     def _tile_range(idx, start, size):
@@ -686,12 +672,13 @@ def _sparse_tile_kernels(
         return jnp.where((idx >= start) & (idx < start + size),
                          idx - start, size)
 
-    def mesh_tile_scatter(g_tile, idx):
-        # Re-base global carrier indices into this device's tile frame;
-        # anything outside the tile becomes an out-of-bounds sentinel
-        # and the drop-mode scatter ignores it. Tiles partition the
-        # (i, j) pair space, so the union over devices is exactly one
-        # +1 per co-occurring pair — the dense path's count.
+    def _tile_frames(idx):
+        # Re-base global carrier indices into this device's row and
+        # column frames; anything outside the tile becomes an
+        # out-of-bounds sentinel that the scatter and the panel builds
+        # drop. Tiles partition the (i, j) pair space, so the union
+        # over devices is exactly one +1 per co-occurring pair — the
+        # dense path's count.
         d_idx, m_idx = _grid_pos()
         li = _tile_range(idx, d_idx * tile_rows, tile_rows)
         lj = _tile_range(idx, m_idx * tile_cols, tile_cols)
@@ -709,7 +696,10 @@ def _sparse_tile_kernels(
                 tile_cols,
                 lj,
             )
-        return _scatter_impl(g_tile, li, lj)
+        return li, lj
+
+    def mesh_tile_scatter(g_tile, idx):
+        return scatter_pairs_chunked(g_tile, *_tile_frames(idx))
 
     scatter = jax.jit(
         jax.shard_map(
@@ -727,24 +717,28 @@ def _sparse_tile_kernels(
         return g + mxu_cross_product(xb, g.dtype, compute_dtype)
 
     @partial(jax.jit, donate_argnums=(0,), static_argnames=("n_cols",))
-    def mesh_tile_mxu(g, pairs, n_cols):
+    def mesh_tile_mxu(g, pairs, n_cols=None):
         # The int8 MXU tile update: each device builds the carrier-count
         # panels of its own row range and its own column range from the
         # window's replicated carrier list (carriers outside a range drop
         # out) and adds P_r · P_cᵀ to its tile — the single-device MXU
         # update cut to a tile. Tiles partition the pair space, so the
-        # union over devices is the window's X·Xᵀ, exactly.
+        # union over devices is the window's X·Xᵀ, exactly; under
+        # ``mirror`` the panels carry the scan's slab partition, and
+        # their product is the masked pair scatter, exactly.
+        # ``pairs`` is a (2, E) carrier list over ``n_cols`` sites
+        # (carrier_pairs), or, with ``n_cols`` None, a pod step's
+        # gathered (V, k) padded carrier matrix: site v's carriers are
+        # row v.
+        if n_cols is None:
+            n_cols = pairs.shape[0]
+            sites = jax.lax.broadcasted_iota(jnp.int32, pairs.shape, 0)
+            pairs = jnp.stack([pairs.ravel(), sites.ravel()])
+
         def body(g_tile, pairs):
-            d_idx, m_idx = _grid_pos()
-            samples, sites = pairs[0], pairs[1]
-            rows = pair_count_panel(
-                _tile_range(samples, d_idx * tile_rows, tile_rows),
-                sites, tile_rows, n_cols,
-            )
-            cols = pair_count_panel(
-                _tile_range(samples, m_idx * tile_cols, tile_cols),
-                sites, tile_cols, n_cols,
-            )
+            li, lj = _tile_frames(pairs[0])
+            rows = pair_count_panel(li, pairs[1], tile_rows, n_cols)
+            cols = pair_count_panel(lj, pairs[1], tile_cols, n_cols)
             # Built apart from the contraction (fused, the panel build
             # would be recomputed per output tile of the matmul).
             rows, cols = jax.lax.optimization_barrier((rows, cols))
@@ -975,15 +969,19 @@ def _synced_carrier_stream(
     dense packed panels; a drained peer's inert all-sentinel block is
     synthesized locally from its header, zero bytes moved) and every
     device re-bases the concatenated global matrix into its tile frame
-    for the same OOB-drop scatter; dense steps carry this process's
+    for the same tile update; dense steps carry this process's
     packed panel columns into the pod dense tile program
     (packed-bytes all_gather inside the shard_map — see
     ``_sparse_tile_kernels``).
 
-    Yields ``(route, global_payload, local_nnz, local_variants, step,
-    local_windows, stream_id)``. Device arrays are built HERE, on the consumer
-    thread — the sync thread never touches jax, so the device
-    collective launch order stays identical on every process.
+    Yields ``(route, global_payload, counts_fit_int8, local_nnz,
+    local_variants, step, local_windows, stream_id)``; on scatter steps
+    ``counts_fit_int8`` is the int8 fit check of the gathered matrix
+    (:func:`spark_examples_tpu.ops.sparse.carrier_matrix_counts_fit_int8`),
+    which every process runs on the same data. Device arrays are built
+    HERE, on the consumer thread — the sync thread never touches jax,
+    so the device collective launch order stays identical on every
+    process.
     """
     from spark_examples_tpu import obs
     from spark_examples_tpu.arrays.blocks import (
@@ -998,6 +996,7 @@ def _synced_carrier_stream(
         _note_pod_gang,
         _note_pod_sync,
         _pad_rows_for_scan,
+        carrier_matrix_counts_fit_int8,
         dense_panel_width,
         padded_carrier_matrix,
         window_route,
@@ -1282,6 +1281,7 @@ def _synced_carrier_stream(
                 "in the payload collective)"
             ) from payload_exc
         gathered = None
+        counts_fit_int8 = False
         if route == "scatter":
             with obs.span(
                 "gramian.sparse.allgather",
@@ -1318,6 +1318,11 @@ def _synced_carrier_stream(
                             )
                         )
                 gathered = np.concatenate(parts, axis=0)
+            # From the gathered matrix, which every process holds
+            # alike: the tile update it selects is congruent.
+            counts_fit_int8 = carrier_matrix_counts_fit_int8(
+                gathered, n_samples
+            )
         _note_pod_sync("synced")
         _note_pod_gang(nwin)
         return PodSlot(
@@ -1328,6 +1333,7 @@ def _synced_carrier_stream(
             nnz=nnz,
             variants=max(rows, 0),
             windows=nwin,
+            counts_fit_int8=counts_fit_int8,
         )
 
     def _produce():
@@ -1374,6 +1380,7 @@ def _synced_carrier_stream(
             item = (
                 slot.route,
                 payload,
+                slot.counts_fit_int8,
                 slot.nnz,
                 slot.variants,
                 slot.step,
@@ -1409,14 +1416,17 @@ def sparse_sharded_gramian_blockwise(
     single device — and each window accumulates WITHOUT densifying:
 
     - sparse windows (density below the threshold,
-      :func:`spark_examples_tpu.ops.sparse.window_route`) scatter their
-      padded carrier matrix into every tile under ``shard_map``: each
+      :func:`spark_examples_tpu.ops.sparse.window_route`) reach every
+      tile under ``shard_map`` as a replicated carrier list: each
       device re-bases the global sample indices into its tile frame,
-      maps out-of-tile carriers to an out-of-bounds sentinel, and the
-      OOB-drop scatter accumulates exactly the pairs that land in its
-      tile. No collective at all — the carrier matrix is replicated
-      (it is ~d·N·V_blk integers, tiny next to the dense block it
-      replaces) and tiles partition the pair space.
+      maps out-of-tile carriers to an out-of-bounds sentinel, and
+      accumulates exactly the pairs that land in its tile — on a TPU
+      by the int8 MXU tile update where every carrier count fits int8,
+      else by the OOB-drop scan
+      (:func:`spark_examples_tpu.ops.sparse.window_scatter_path`). No
+      collective at all — the carriers are ~d·N·V_blk integers, tiny
+      next to the dense block they replace, and tiles partition the
+      pair space.
     - dense windows densify + bit-pack onto the existing MXU
       accumulator with G kept in the same tiled layout (GSPMD gathers
       the block columns; G never moves — the
@@ -1439,7 +1449,7 @@ def sparse_sharded_gramian_blockwise(
     padded carrier matrices allgather cross-host (~d·N·V_blk sparse
     integers per window instead of dense packed panels) and every
     device re-bases the concatenated global matrix into its tile frame
-    for the same OOB-drop scatter — zero new N×N anywhere. Dense-route
+    for the same tile update — zero new N×N anywhere. Dense-route
     windows of a mixed stream ride the existing packed pod collective.
     Pod ingest ships FULL sample-range windows (each host is the source
     of its variants for every peer's tiles), so the sample-range
@@ -1463,6 +1473,7 @@ def sparse_sharded_gramian_blockwise(
         dense_panel_width,
         padded_carrier_matrix,
         window_route,
+        window_scatter_path,
     )
 
     if density_threshold is None:
@@ -1481,16 +1492,6 @@ def sparse_sharded_gramian_blockwise(
         jnp.int8, accum_dtype, compute_dtype
     )
     width = block_variants or DEFAULT_BLOCK_VARIANTS
-    from spark_examples_tpu.ops.scatter_kernel import (
-        resolve_scatter_path,
-        window_scatter_path,
-    )
-
-    # One scan-vs-Pallas resolution per stream, OUTSIDE any trace; part
-    # of the executable cache key so the env switch is honored per run.
-    scatter_path = resolve_scatter_path(
-        (tile_rows, tile_cols), np.dtype(accum_dtype)
-    )
     # Square pod tile grids skip the strictly-lower (transpose-
     # redundant) tiles during accumulation and mirror once at the end —
     # see _sparse_tile_kernels. Pod-only: the host-local path's G may
@@ -1498,7 +1499,13 @@ def sparse_sharded_gramian_blockwise(
     mirror = (
         spans and grid_rows == grid_cols and grid_rows > 1
     )
-    kernel_key = (
+    (
+        _tile_scan,
+        _accum_dense,
+        _accum_dense_pod,
+        _mirror_fill,
+        _tile_mxu,
+    ) = _sparse_tile_kernels(
         mesh,
         d_axis,
         m_axis,
@@ -1507,35 +1514,24 @@ def sparse_sharded_gramian_blockwise(
         tile_cols,
         np.dtype(accum_dtype).name,
         np.dtype(compute_dtype).name,
+        mirror,
     )
-    (
-        _,
-        _accum_dense,
-        _accum_dense_pod,
-        _mirror_fill,
-        _tile_mxu,
-    ) = _sparse_tile_kernels(*kernel_key, scatter_path, mirror)
 
-    def scatter(g, idx):
-        # The carrier bucket K is known only per window: the stream's
-        # kernel path may have to take the scan kernel set for this one
-        # (window_scatter_path counts that fall).
-        path = window_scatter_path(
-            scatter_path, (tile_rows, tile_cols), int(idx.shape[1])
-        )
-        return _sparse_tile_kernels(*kernel_key, path, mirror)[0](g, idx)
+    def scatter_step(g, idx, counts_fit_int8):
+        # A pod step's gathered carrier matrix: its bucket and its fit
+        # are alike on every process, so every process takes the same
+        # tile program.
+        path = window_scatter_path(int(idx.shape[1]), counts_fit_int8)
+        if path == "mxu-tile":
+            return _tile_mxu(g, idx)
+        return _tile_scan(g, idx)
 
     def scatter_window(g, window_idx, lens):
         # Host-local tiles: the int8 MXU tile update where the host has
-        # checked every carrier count fits int8, else the carrier matrix
-        # through the stream's scatter program.
+        # checked every carrier count fits int8, else the tile scan.
         path = window_scatter_path(
-            scatter_path,
-            (tile_rows, tile_cols),
             _carrier_bucket(int(lens.max()) if lens.size else 0),
-            tile_counts_fit_int8=carrier_counts_fit_int8(
-                window_idx, lens, n_samples
-            ),
+            carrier_counts_fit_int8(window_idx, lens, n_samples),
         )
         if path == "mxu-tile":
             pairs = carrier_pairs(window_idx, lens, sentinel=n_padded)
@@ -1550,8 +1546,7 @@ def sparse_sharded_gramian_blockwise(
             sentinel=n_padded,
             n_rows=_pad_rows_for_scan(lens.size),
         )
-        kernels = _sparse_tile_kernels(*kernel_key, path, mirror)
-        return kernels[0](g, jax.device_put(idx, idx_sharding))
+        return _tile_scan(g, jax.device_put(idx, idx_sharding))
 
     idx_sharding = NamedSharding(mesh, P(None, None))
     g = _sharded_zeros(
@@ -1584,6 +1579,7 @@ def sparse_sharded_gramian_blockwise(
             for (
                 route,
                 payload,
+                counts_fit_int8,
                 nnz,
                 n_variants,
                 step,
@@ -1600,7 +1596,7 @@ def sparse_sharded_gramian_blockwise(
                     windows=n_win,
                 ):
                     if route == "scatter":
-                        g = scatter(g, payload)
+                        g = scatter_step(g, payload, counts_fit_int8)
                     else:
                         g = _accum_dense_pod(g, payload)
                 _note_window(route, nnz, count=n_win)
@@ -2164,6 +2160,7 @@ def sharded_sketch_panel(
                 for (
                     route,
                     payload,
+                    _,
                     nnz,
                     n_variants,
                     step,

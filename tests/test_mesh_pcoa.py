@@ -46,8 +46,7 @@ PATHS = "sparse_scatter_path_windows_total"
 
 def _paths():
     counter = obs.get_registry().counter(PATHS)
-    return {p: counter.labels(path=p).value
-            for p in ("mxu-tile", "scan", "fallback-scan")}
+    return {p: counter.labels(path=p).value for p in ("mxu-tile", "scan")}
 
 
 def _driver(mesh_shape=None, mesh=None, **conf_kw):
@@ -166,8 +165,6 @@ class TestTileMxuUpdate:
                 density_threshold=0.99, block_variants=128,
             ))
 
-        monkeypatch.delenv("SPARK_EXAMPLES_TPU_SCATTER_KERNEL",
-                           raising=False)
         scan = accumulate()
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         before = _paths()
@@ -179,19 +176,6 @@ class TestTileMxuUpdate:
         fell = 1 if case == "overflow" else 0
         assert after["mxu-tile"] - before["mxu-tile"] == windows - fell
         assert after["scan"] - before["scan"] == fell
-
-    def test_kill_switch_keeps_the_scan(self, monkeypatch):
-        idx, offsets, x = _window_case("mixed", 301)
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setenv("SPARK_EXAMPLES_TPU_SCATTER_KERNEL", "0")
-        before = _paths()
-        got = np.asarray(sparse_sharded_gramian_blockwise(
-            csr_windows(iter([(idx, offsets)]), 128), 301,
-            make_mesh("data:2,model:2"), density_threshold=0.99,
-            block_variants=128,
-        ))
-        np.testing.assert_array_equal(got, (x @ x.T).astype(np.float32))
-        assert _paths()["mxu-tile"] == before["mxu-tile"]
 
 
 def _ukb_cohort(n_eur=400, others=100, sites=20000, rare_max=300):
@@ -260,7 +244,6 @@ def test_harness_analysis_on_four_devices(tmp_path, monkeypatch):
             "--sample-sharded", "--dense-eigh-limit", "256",
             "--sparse-density-threshold", "0.5"]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("SPARK_EXAMPLES_TPU_SCATTER_KERNEL", raising=False)
     before = _paths()
     try:
         g, coords, rows = run.analysis(cohort_dir, cohort.references(),
@@ -271,7 +254,6 @@ def test_harness_analysis_on_four_devices(tmp_path, monkeypatch):
     assert len(g.sharding.device_set) == 4
     assert after["mxu-tile"] > before["mxu-tile"]
     assert after["scan"] == before["scan"]
-    assert after["fallback-scan"] == before["fallback-scan"]
     checks = run.compare(cohort, [(np.asarray(g), coords, rows)], 2,
                          {"g_err": 0.0, "coord_err": 1e-6})
     assert checks["g_err"]["value"] == 0.0

@@ -900,6 +900,32 @@ _POD_SPARSE_WORKER = textwrap.dedent(
         if e.get("ph") == "X" and e["name"] == "gramian.sparse.slot"
     )
 
+    # 8. The TPU's scatter rule on the pod stream (backend patched):
+    # steps whose gathered matrix fits int8 take the MXU tile update; a
+    # step whose carrier repeats 130 times at one site takes the scan.
+    paths = obs.get_registry().counter("sparse_scatter_path_windows_total")
+    before_paths = {p: paths.labels(path=p).value for p in ("scan", "mxu-tile")}
+    cpu_backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        g8 = sparse_sharded_gramian_blockwise(
+            iter(mine), n, mesh, block_variants=32,
+            density_threshold=1.01,  # every step on the scatter route
+        )
+        out["g_mxu_tile"] = np.asarray(replicate(g8)).tolist()
+        repeat = (np.full(130, pid, np.int64), np.array([130], np.int64))
+        g9 = sparse_sharded_gramian_blockwise(
+            iter([repeat]), 19, mesh, density_threshold=1e9,
+            block_variants=8,
+        )
+        out["g_past_int8"] = np.asarray(replicate(g9)).tolist()
+    finally:
+        jax.default_backend = cpu_backend
+    out["pod_paths"] = {
+        p: paths.labels(path=p).value - before_paths[p]
+        for p in ("scan", "mxu-tile")
+    }
+
     if pid == 0:
         with open(sys.argv[1], "w") as f:
             json.dump(out, f)
@@ -1212,6 +1238,19 @@ class TestPodSparseProtocol:
         # made it onto the timeline.
         assert result["overlap_proven"] is True
         assert result["slot_spans"] >= 2
+
+        # The TPU's rule on the pod stream: the MXU tile update (mirrored
+        # on the square 2-process grid) gives the same G; the step past
+        # int8 took the scan, exactly.
+        np.testing.assert_array_equal(np.asarray(result["g_mxu_tile"]), want)
+        want_r = np.zeros((19, 19), np.float32)
+        for p in range(nprocs):
+            want_r[p, p] = 130.0**2
+        np.testing.assert_array_equal(
+            np.asarray(result["g_past_int8"]), want_r
+        )
+        assert result["pod_paths"]["scan"] == 1
+        assert result["pod_paths"]["mxu-tile"] >= 1
 
     @pytest.mark.parametrize("nprocs", [2, 4])
     def test_pod_failure_sync_chaos(self, tmp_path, nprocs):

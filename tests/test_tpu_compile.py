@@ -2,8 +2,8 @@
 
 No chip is attached here: the TPU compiler compiles for a topology that
 is only described (``jax.experimental.topologies``), which catches what
-interpret mode and the CPU backend cannot — a Pallas kernel Mosaic
-refuses, a block over the VMEM budget, a program that does not fit.
+the CPU backend cannot — an operation the TPU compiler refuses, a
+program that does not fit a chip's memory.
 Nothing runs, so these tests say nothing about results or times.
 
 The topology is described only inside the module fixture below (never at
@@ -50,28 +50,6 @@ def one_chip(topo):
 
 def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-
-@pytest.mark.parametrize("k", [8, 512])
-def test_scatter_kernel_compiles_with_mosaic(one_chip, k):
-    """The Pallas scatter kernel, compiled (``interpret=False``) at a real
-    (4096, 4096) f32 tile, for the smallest carrier bucket and one near
-    the VMEM budget's limit at this tile width."""
-    from spark_examples_tpu.ops.scatter_kernel import (
-        kernel_block_rows,
-        scatter_pairs_kernel,
-    )
-
-    assert kernel_block_rows(4096, 4096, k) is not None
-    compiled = (
-        jax.jit(lambda g, i: scatter_pairs_kernel(g, i, i, interpret=False))
-        .lower(
-            _spec((4096, 4096), jnp.float32, one_chip),
-            _spec((512, k), jnp.int32, one_chip),
-        )
-        .compile()
-    )
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_fused_finish_compiles_at_north_star(one_chip):
@@ -126,26 +104,21 @@ def test_scatter_route_mxu_update_compiles(one_chip):
     assert compiled.memory_analysis().output_size_in_bytes >= 3202 * 3202 * 4
 
 
-@pytest.mark.parametrize("k", [64, 512])
-def test_sharded_scatter_kernel_compiles_on_2x2_mesh(topo, k):
-    """The same kernel inside the mesh engine's shard_map tile program
-    (``--pca-mode sparse --mesh-shape data:2,model:2``), (2048, 2048)
-    tiles of an N=4096 G: the kernel's output must carry the tile's
-    varying mesh axes, which interpret mode never checks."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+def test_scatter_built_update_compiles_at_bucket_512(one_chip):
+    """A single-device window above bucket 127: the panel scatter-built
+    from a carrier list of 2^20 entries over 8,192 sites of a
+    (4,096, 4,096) G, then the int8 contraction."""
+    from spark_examples_tpu.ops.sparse import _scatter_accumulate_jit
 
-    from spark_examples_tpu.parallel.sharded import _sparse_tile_kernels
-
-    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("data", "model"))
-    scatter = _sparse_tile_kernels(
-        mesh, "data", "model", 4096, 2048, 2048, "float32", "int8",
-        "pallas", False,
-    )[0]
-    compiled = scatter.lower(
-        _spec((4096, 4096), jnp.float32, NamedSharding(mesh, P("data", "model"))),
-        _spec((8192, k), jnp.int32, NamedSharding(mesh, P(None, None))),
+    compiled = _scatter_accumulate_jit.lower(
+        _spec((4096, 4096), jnp.float32, one_chip),
+        _spec((2, 1 << 20), jnp.int32, one_chip),
+        path="mxu-tile",
+        compute_dtype=jnp.int8,
+        n_cols=8192,
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "convolution" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes >= 4096 * 4096 * 4
 
 
 def _mesh_2x2(topo):
@@ -167,7 +140,6 @@ def test_mesh_tile_mxu_update_compiles_at_ukb50k(topo):
     mesh = _mesh_2x2(topo)
     update = _sparse_tile_kernels(
         mesh, "data", "model", 49960, 24980, 24980, "float32", "int8",
-        "scan", False,
     )[4]
     compiled = update.lower(
         _spec((49960, 49960), jnp.float32,
@@ -180,6 +152,27 @@ def test_mesh_tile_mxu_update_compiles_at_ukb50k(topo):
     tile = 24980 * 24980 * 4
     assert mem.output_size_in_bytes >= tile
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_pod_mirror_tile_update_compiles_on_2x2_mesh(topo):
+    """A pod step's int8 MXU tile update under the mirror partition:
+    panels scatter-built from a gathered (16,384, 512) carrier matrix
+    into (2,048, 2,048) tiles of an N = 4,096 G on the 2x2 mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_examples_tpu.parallel.sharded import _sparse_tile_kernels
+
+    mesh = _mesh_2x2(topo)
+    update = _sparse_tile_kernels(
+        mesh, "data", "model", 4096, 2048, 2048, "float32", "int8", True,
+    )[4]
+    compiled = update.lower(
+        _spec((4096, 4096), jnp.float32,
+              NamedSharding(mesh, P("data", "model"))),
+        _spec((16384, 512), jnp.int32, NamedSharding(mesh, P(None, None))),
+    ).compile()
+    assert "convolution" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes >= 2048 * 2048 * 4
 
 
 def test_mesh_finish_compiles_at_ukb50k(topo):

@@ -2,7 +2,7 @@
 backend is a TPU and skip everywhere else.
 
 The main ``tests/`` suite forces an 8-device virtual CPU mesh and can
-never certify that the Pallas kernels lower and run bit-exact on the
+never certify that the TPU's compiled programs run bit-exact on the
 real chip. Run this directory on the chip through the chip tool
 (``python -m pytest tests_tpu/ -q``); on a CPU-only machine every test
 skips from the ``tpu`` fixture. jax imports stay inside fixtures and test

@@ -55,8 +55,7 @@ def test_mxu_tile_update_matches_the_scan_on_four_chips(tpu):
         pytest.skip("needs four chips")
     mesh = make_mesh("data:2,model:2")
     kernels = _sparse_tile_kernels(
-        mesh, "data", "model", N, TILE, TILE, "float32", "int8", "scan",
-        False,
+        mesh, "data", "model", N, TILE, TILE, "float32", "int8",
     )
     tiled = NamedSharding(mesh, P("data", "model"))
     rep = NamedSharding(mesh, P(None, None))
