@@ -314,12 +314,14 @@ _WIRE_HISTOGRAM = "wire_frame_decode_seconds"
 
 # Parallel-ingest metric contract: the block and carrier counters carry
 # a mode label ("native"/"python" — which path built the block or
-# gathered the carriers), and the build-latency histogram exposes the
-# full Prometheus triplet. Checked only when present, like the wire
-# metrics.
+# gathered the carriers), the sidecar CRC byte counter one too
+# ("parallel"/"serial" — how each member's CRC was checked), and the
+# build-latency histogram exposes the full Prometheus triplet. Checked
+# only when present, like the wire metrics.
 _INGEST_COUNTERS = (
     "ingest_blocks_built_total",
     "ingest_carriers_gathered_total",
+    "ingest_sidecar_crc_bytes_total",
 )
 _INGEST_HISTOGRAM = "ingest_block_build_seconds"
 

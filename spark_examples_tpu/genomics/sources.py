@@ -23,6 +23,7 @@ gives the reference (VariantsRDD.scala:210-211).
 
 from __future__ import annotations
 
+import functools
 import gzip
 import json
 import os
@@ -1018,7 +1019,107 @@ def _line_vsid_matches(line: bytes, variant_set_id: str) -> bool:
     return not stored or stored == variant_set_id
 
 
-def _load_sidecar_mmap(path: str):
+# CRC-32 (zlib's reflected polynomial) arithmetic for _crc32_combine:
+# bit 31 of a value is the coefficient of x^0. _CRC_X2N[n] = x^(2^n)
+# modulo the polynomial, as zlib's x2n_table.
+_CRC_POLY = 0xEDB88320
+
+
+def _crc_multmodp(a: int, b: int) -> int:
+    """a·b modulo the CRC-32 polynomial (zlib's multmodp); a nonzero."""
+    m, p = 1 << 31, 0
+    while True:
+        if a & m:
+            p ^= b
+            if not a & (m - 1):
+                return p
+        m >>= 1
+        b = (b >> 1) ^ _CRC_POLY if b & 1 else b >> 1
+
+
+def _crc_x2n() -> List[int]:
+    table = [1 << 30]  # x^1
+    while len(table) < 32:
+        table.append(_crc_multmodp(table[-1], table[-1]))
+    return table
+
+
+_CRC_X2N = _crc_x2n()
+
+
+def _crc32_combine(crc1: int, crc2: int, len2: int) -> int:
+    """CRC-32 of A‖B from crc1 = crc(A), crc2 = crc(B) and len2 = len(B)
+    bytes: zlib's ``crc32_combine``, which CPython's ``zlib`` does not
+    expose. crc1 is multiplied by x^(8·len2) modulo the polynomial, one
+    table factor per set bit of len2, then crc2 added: O(log len2)
+    32-bit steps."""
+    p, k = 1 << 31, 3  # x^0; len2 bytes are len2·2^3 bits
+    while len2:
+        if len2 & 1:
+            p = _crc_multmodp(_CRC_X2N[k & 31], p)
+        len2 >>= 1
+        k += 1
+    return _crc_multmodp(p, crc1) ^ crc2
+
+
+# A sidecar member of at least two chunks has its CRC checked on up to
+# _crc_threads() threads, a contiguous chunk each (zlib.crc32 releases
+# the GIL), folded left to right by _crc32_combine. Smaller members take
+# one zlib.crc32 call.
+_CRC_CHUNK_BYTES = 16 << 20
+
+
+@functools.cache
+def _crc_threads() -> int:
+    # Cached: every member of every load asks, and the count is fixed
+    # while the process runs.
+    return min(8, os.cpu_count() or 1)
+
+
+def _crc_parts(size: int) -> int:
+    """Chunks (so threads) of a ``size``-byte member's CRC check."""
+    return max(1, min(_crc_threads(), size // _CRC_CHUNK_BYTES))
+
+
+def _record_sidecar_crc_bytes(mode: str, n: int) -> None:
+    from spark_examples_tpu import obs
+
+    obs.get_registry().counter(
+        "ingest_sidecar_crc_bytes_total",
+        "Sidecar member bytes whose CRC was checked on load",
+    ).labels(mode=mode).inc(n)
+
+
+def _crc32_parallel(view, pool=None) -> int:
+    """``zlib.crc32(view)``, bit for bit, on several threads when
+    ``view`` (a byte memoryview) holds at least two chunks: at most
+    ``_crc_threads()`` chunks of at least ``_CRC_CHUNK_BYTES``, each a
+    zero-copy slice of ``view``, run on ``pool`` (a thread pool of its
+    own when None)."""
+    import zlib
+
+    size = view.nbytes
+    parts = _crc_parts(size)
+    if parts == 1:
+        _record_sidecar_crc_bytes("serial", size)
+        return zlib.crc32(view)
+    if pool is None:
+        from concurrent.futures import ThreadPoolExecutor
+
+        with ThreadPoolExecutor(max_workers=parts) as own:
+            return _crc32_parallel(view, own)
+    step = size // parts
+    starts = [i * step for i in range(parts)]
+    ends = starts[1:] + [size]
+    crcs = pool.map(lambda a, b: zlib.crc32(view[a:b]), starts, ends)
+    crc = 0
+    for a, b, c in zip(starts, ends, crcs):
+        crc = _crc32_combine(crc, c, b - a)
+    _record_sidecar_crc_bytes("parallel", size)
+    return crc
+
+
+def _load_sidecar_mmap(path: str, check: Optional[dict] = None):
     """The sidecar npz as zero-copy views over ONE sequential-readahead
     mmap of the file, or None when the layout forbids it (compressed
     members, object dtypes, or any parse anomaly — the caller then
@@ -1034,17 +1135,25 @@ def _load_sidecar_mmap(path: str):
     of being decompressed-copied into anonymous memory — and the page
     cache is shared across concurrent serving processes.
     ``SPARK_EXAMPLES_TPU_SIDECAR_MMAP=0`` disables (docs/OPERATIONS.md).
+
+    ``check``, when given, receives the member bytes CRC-checked
+    (``bytes``) and the most threads one member's check used
+    (``threads``).
     """
     import io
     import mmap as _mmap
     import struct
     import zipfile
-    import zlib
+    from concurrent.futures import ThreadPoolExecutor
 
     from numpy.lib import format as npformat
 
     if os.environ.get("SPARK_EXAMPLES_TPU_SIDECAR_MMAP", "") == "0":
         return None
+    check = {} if check is None else check
+    check.update(bytes=0, threads=0)
+    # Threads start on the first multi-chunk member's check, not here.
+    pool = ThreadPoolExecutor(max_workers=_crc_threads())
     try:
         with zipfile.ZipFile(path) as zf:
             infos = zf.infolist()
@@ -1066,12 +1175,17 @@ def _load_sidecar_mmap(path: str):
             off = ho + 30 + nlen + elen
             # The CRC check np.load would have done through ZipExtFile:
             # a corrupted-but-committed member must trigger the rebuild/
-            # re-mirror fallback, never serve garbage ordinals. One
-            # zero-copy sequential pass — exactly what the readahead
-            # hint is for; still strictly cheaper than the copy loader.
-            if (
-                zlib.crc32(view[off : off + info.file_size]) & 0xFFFFFFFF
-            ) != info.CRC:
+            # re-mirror fallback, never serve garbage ordinals. Zero-copy
+            # passes over the member, one per chunk, each sequential —
+            # what the readahead hint is for; still strictly cheaper
+            # than the copy loader.
+            member = view[off : off + info.file_size]
+            crc = _crc32_parallel(member, pool)
+            check["bytes"] += info.file_size
+            check["threads"] = max(
+                check["threads"], _crc_parts(info.file_size)
+            )
+            if crc != info.CRC:
                 return None
             # The npy header is tiny; hand the parser a bounded window.
             fp = io.BytesIO(mm[off : off + min(info.file_size, 1 << 16)])
@@ -1094,6 +1208,8 @@ def _load_sidecar_mmap(path: str):
         return data
     except Exception:  # noqa: BLE001 — any layout anomaly: copy path
         return None
+    finally:
+        pool.shutdown()
 
 
 def _native_gather_layout(offsets: np.ndarray, ords: np.ndarray) -> bool:
@@ -1198,7 +1314,12 @@ class _CsrCohort:
         return complete and bool(ident) and ident == ok
 
     @classmethod
-    def load_or_build(cls, root: str, open_fn) -> "_CsrCohort":
+    def load_or_build(
+        cls, root: str, open_fn, check: Optional[dict] = None
+    ) -> "_CsrCohort":
+        """The cohort from its sidecar, or parsed (and the sidecar
+        written) when there is none or it fails its checks; ``check`` as
+        for :func:`_load_sidecar_mmap`."""
         sidecar = os.path.join(root, SIDECAR_BASENAME)
         src_paths = []
         for name in ("variants.jsonl", "callsets.json"):
@@ -1219,7 +1340,7 @@ class _CsrCohort:
             try:
                 # mmap-with-readahead view first (zero-copy re-reads —
                 # the cold-path restart tier); np.load copy fallback.
-                data = _load_sidecar_mmap(sidecar)
+                data = _load_sidecar_mmap(sidecar, check)
                 if data is None:
                     data = dict(np.load(sidecar, allow_pickle=False))
                 stored = str(data["digest"])
@@ -1916,11 +2037,12 @@ class JsonlSource:
 
             with self._lazy_lock:
                 if self._csr is None:
-                    # Once per source: the load (and CRC pass) of the
-                    # sidecar, inside the first shard's ingest.fetch.
-                    with obs.span("ingest.sidecar_open"):
+                    # Once per source: the load (and CRC check) of the
+                    # sidecar, inside the first shard's ingest.fetch;
+                    # the check's bytes and threads land in its args.
+                    with obs.span("ingest.sidecar_open") as args:
                         self._csr = _CsrCohort.load_or_build(
-                            self.root, self._open
+                            self.root, self._open, args
                         )
         return self._csr
 

@@ -503,14 +503,22 @@ class TestMirrorDurability:
 
         assert refresh_cold_stream(_FlakySource()) is False
 
+    @pytest.mark.parametrize(
+        "flip", ["file_middle", "first_chunk", "middle_chunk", "last_chunk"]
+    )
     def test_corrupt_sidecar_member_falls_back_and_rebuilds(
-        self, cohort_dir, tmp_path
+        self, cohort_dir, tmp_path, monkeypatch, flip
     ):
         """mmap fast path keeps np.load's corruption detection: a
         bit-flipped committed sidecar must fail its CRC and trigger the
-        rebuild, never serve garbage ordinals."""
+        rebuild, never serve garbage ordinals. The ``*_chunk`` cases
+        check the ``ords`` member in four chunks, on threads, and flip
+        a byte inside one of them."""
         import shutil as _shutil
+        import struct
+        import zipfile
 
+        from spark_examples_tpu import obs
         from spark_examples_tpu.genomics import sources as S
 
         root = str(tmp_path / "cohort")
@@ -520,7 +528,32 @@ class TestMirrorDurability:
             c.id for c in JsonlSource(cohort_dir).list_callsets(VSID)
         ]
         blob = bytearray(open(side, "rb").read())
-        blob[len(blob) // 2] ^= 0xFF  # flip a payload bit
+        with zipfile.ZipFile(side) as zf:
+            infos = zf.infolist()
+        if flip == "file_middle":
+            at = len(blob) // 2  # flip a payload bit
+        else:
+            (ords,) = [i for i in infos if i.filename == "ords.npy"]
+            chunk = ords.file_size // 4
+            monkeypatch.setattr(S, "_CRC_CHUNK_BYTES", chunk)
+            monkeypatch.setattr(S, "_crc_threads", lambda: 8)
+            multi = sum(i.file_size for i in infos
+                        if i.file_size >= 2 * chunk)
+            assert multi >= ords.file_size
+            counters = obs.get_registry().snapshot()["counters"]
+            key = 'ingest_sidecar_crc_bytes_total{mode="parallel"}'
+            before = counters.get(key, 0.0)
+            assert S._load_sidecar_mmap(side) is not None  # intact
+            after = obs.get_registry().snapshot()["counters"][key]
+            assert after - before == multi
+            ho = ords.header_offset
+            nlen, elen = struct.unpack("<HH", blob[ho + 26 : ho + 30])
+            start = ho + 30 + nlen + elen
+            index = {"first_chunk": 0, "middle_chunk": 2,
+                     "last_chunk": 3}[flip]
+            # The last chunk runs to the member's end.
+            at = start + index * chunk + chunk // 2
+        blob[at] ^= 0xFF
         with open(side, "wb") as f:
             f.write(blob)
         assert S._load_sidecar_mmap(side) is None  # CRC catches it

@@ -152,6 +152,33 @@ def test_sidecar_opens_once_per_source_however_many_shards(light_mirror):
     assert len(opens) == 2  # one per source
 
 
+def test_sidecar_open_span_carries_the_checks_bytes_and_threads(
+        light_mirror, monkeypatch):
+    """The open's args: every member's bytes CRC-checked, and the most
+    threads one member's check took (its chunks, here with chunks small
+    enough that the larger members split)."""
+    import zipfile
+
+    from spark_examples_tpu.genomics import sources
+    from spark_examples_tpu.genomics.shards import Shard
+
+    chunk = 512
+    monkeypatch.setattr(sources, "_CRC_CHUNK_BYTES", chunk)
+    monkeypatch.setattr(sources, "_crc_threads", lambda: 4)
+    side = os.path.join(light_mirror, sources.SIDECAR_BASENAME)
+    with zipfile.ZipFile(side) as zf:
+        sizes = [i.file_size for i in zf.infolist()]
+    assert max(sizes) >= 2 * chunk
+    source = sources.JsonlSource(light_mirror)
+    with obs.TelemetrySession(annotate_jax=False) as session:
+        source.stream_carrying_csr(
+            "", Shard("17", 41196311, 41277499), _index(source), None)
+    (opened,) = _events(session, "ingest.sidecar_open")
+    assert opened["args"]["bytes"] == sum(sizes)
+    assert opened["args"]["threads"] == max(
+        min(4, size // chunk) for size in sizes)
+
+
 def test_feed_spans_stay_out_of_the_ingest_family():
     """``ingest_wall_s`` unions every ``ingest.*`` span: the dispatcher's
     waits must not be counted as ingest."""

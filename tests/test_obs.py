@@ -660,6 +660,44 @@ class TestIngestSpanEmission:
         errs = validate.validate_metrics(str(path))
         assert errs and "mode" in errs[0]
 
+    def test_sidecar_crc_counter_counts_each_members_bytes(self, tmp_path):
+        """A second source over a built sidecar checks every member's
+        CRC: ``ingest_sidecar_crc_bytes_total{mode}`` counts the
+        members' bytes, all ``serial`` at a test cohort's size."""
+        import zipfile
+
+        from spark_examples_tpu.genomics.fixtures import synthetic_cohort
+        from spark_examples_tpu.genomics.shards import Shard
+        from spark_examples_tpu.genomics.sources import (
+            SIDECAR_BASENAME,
+            JsonlSource,
+        )
+
+        root = str(tmp_path / "cohort")
+        synthetic_cohort(10, 60, seed=3).dump(root)
+        shard = Shard("17", 41196311, 41277499)
+        builder = JsonlSource(root)
+        index = {c.id: i for i, c in enumerate(builder.list_callsets(""))}
+        builder.stream_carrying_csr("", shard, index, None)
+        with zipfile.ZipFile(os.path.join(root, SIDECAR_BASENAME)) as zf:
+            members = sum(i.file_size for i in zf.infolist())
+        with TelemetrySession() as s:
+            JsonlSource(root).stream_carrying_csr("", shard, index, None)
+        counters = s.registry.snapshot()["counters"]
+        key = 'ingest_sidecar_crc_bytes_total{mode="%s"}'
+        assert counters[key % "serial"] == members
+        assert counters.get(key % "parallel", 0) == 0
+
+    def test_validator_rejects_modeless_sidecar_crc_counter(self, tmp_path):
+        path = tmp_path / "bad.metrics.prom"
+        path.write_text(
+            "# HELP ingest_sidecar_crc_bytes_total bytes\n"
+            "# TYPE ingest_sidecar_crc_bytes_total counter\n"
+            "ingest_sidecar_crc_bytes_total 4096\n"
+        )
+        errs = validate.validate_metrics(str(path))
+        assert errs and "mode" in errs[0]
+
     def test_validator_rejects_modeless_carriers_counter(self, tmp_path):
         path = tmp_path / "bad.metrics.prom"
         path.write_text(
